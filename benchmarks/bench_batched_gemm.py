@@ -50,4 +50,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

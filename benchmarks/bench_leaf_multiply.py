@@ -122,6 +122,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     if args.compare_backends:
+        from repro.launch.compile_cache import use_compile_cache
+        use_compile_cache()
         print(json.dumps(compare_backends(args.n, args.pattern, args.leaf_n,
                                           args.bs, args.seed), indent=2))
     else:

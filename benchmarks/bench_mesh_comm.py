@@ -16,8 +16,11 @@ uniform by construction so the HLO number *is* the per-device number).
 Expected Table-1 shape: parent-worker stays roughly flat with p on a
 banded (local) pattern; SpSUMMA grows ~sqrt(p).
 
-Runs itself in subprocesses (device count must be set before jax init).
-Writes ``BENCH_mesh_comm.json`` at the repo root (or ``--out``).
+A virtual-device study: it runs itself in subprocesses pinned to the CPU
+(``JAX_PLATFORMS=cpu``) with 2-16 forced host devices, since the device
+count must be set before jax initialises and a chip host has fewer
+devices than that.  Writes ``BENCH_mesh_comm.json`` at the repo root (or
+``--out``).
 """
 import argparse
 import json
@@ -110,7 +113,7 @@ def main() -> int:
     records = []
     root = pathlib.Path(__file__).parents[1]
     for scheme, p, n in runs:
-        env = dict(os.environ)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
         if scheme == "mesh" and p == max(MESH_PS):
             # largest mesh run also emits its per-wave device trace

@@ -193,4 +193,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

@@ -5,7 +5,11 @@ Morton-locality halo exchange (core/distributed.py) vs SpSUMMA
 all_gather (core/spsumma.py).  Collective bytes per device are parsed
 from the optimized SPMD module — the dry-run methodology end-to-end.
 
-Runs itself in subprocesses (device count must be set before jax init).
+A virtual-device study: it runs itself in subprocesses pinned to the CPU
+(``JAX_PLATFORMS=cpu``) with 4-64 forced host devices, since the device
+count must be set before jax initialises and a chip host has fewer
+devices than that.  The collective bytes come from the CPU backend's SPMD
+partitioning of the same program.
 CSV: scheme,p,N,coll_bytes_per_dev,halo_hops_or_pgrid.
 """
 import os
@@ -66,7 +70,7 @@ def main() -> None:
     for p in (4, 16, 64):
         n = 256 * p
         for scheme in ("halo", "demand", "summa"):
-            env = dict(os.environ)
+            env = dict(os.environ, JAX_PLATFORMS="cpu")
             env["XLA_FLAGS"] = \
                 f"--xla_force_host_platform_device_count={p}"
             res = subprocess.run(

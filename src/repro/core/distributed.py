@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from . import morton
 from .blocksparse import _np_pyramid, enumerate_pairs_hier, mask_pyramid
@@ -329,11 +328,11 @@ def halo_spmm(mesh: Mesh, axis: str, plan: DistPlan,
         return (cb[None], crows[None], ccols[None], n_pairs[None])
 
     spec = P(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec,) * 6,
         out_specs=(spec, spec, spec, spec),
-        check_rep=False)
+        check_vma=False)
     return fn(a_blocks, a_rows, a_cols, b_blocks, b_rows, b_cols)
 
 
@@ -586,8 +585,8 @@ def demand_spmm(mesh: Mesh, axis: str, plan: DemandPlan,
         return cb_[None], crows[None], ccols[None], n_pairs[None]
 
     spec = P(axis)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,) * 8,
-                   out_specs=(spec,) * 4, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 8,
+                       out_specs=(spec,) * 4, check_vma=False)
     return fn(a_blocks, a_rows, a_cols, b_blocks, b_rows, b_cols,
               selA, selB)
 

@@ -402,15 +402,17 @@ class PallasEngine(LeafEngine):
     """Deferred, cross-leaf-batched execution through the Pallas kernels.
 
     Precision contract: operands are packed float32 and the kernels
-    accumulate in float32 (jax runs without x64 here), so engine-produced
+    accumulate in float32 with full-precision MXU passes
+    (``Precision.HIGHEST``; jax runs without x64 here), so engine-produced
     leaves are float32 regardless of input dtype — expect ~1e-7 relative
     agreement with the float64 numpy backend, not bitwise equality.
 
     kernel   : 'pairs' -> one fused kernels.bsmm_pairs gather-GEMM-scatter
                call per wave; 'gemm' -> one kernels.batched_gemm call per
                wave + host scatter-add (the cuBLAS-batched-gemm shape).
-    interpret: None -> auto (Pallas interpret mode off-TPU, compiled on TPU;
-               resolved by kernels.ops).
+    interpret: None -> auto (compiled on TPU, interpret mode on CPU, any
+               other backend raises; resolved by kernels.ops.resolve and
+               recorded in every wave record with the result's platform).
     block_t  : batch tile of the batched_gemm kernel, which zero-pads the
                wave to a multiple of it internally.
     validate_structure : cross-check the pure-Python output structure of
@@ -854,14 +856,15 @@ def dispatch_solve_wave(tasks: list[_Pending], *, kind: str, n: int,
     a_pack = np.stack([t.a_leaf.to_dense() for t in tasks]).astype(np.float32)
     t0 = time.perf_counter()
     if kind == "inv_chol":
-        res = np.asarray(ktri.batched_inv_chol(jnp.asarray(a_pack)))
+        res_dev = ktri.batched_inv_chol(jnp.asarray(a_pack))
         b_bytes = 0
     else:
         b_pack = np.stack([t.b_leaf.to_dense()
                            for t in tasks]).astype(np.float32)
-        res = np.asarray(ktri.batched_tri_solve(
-            jnp.asarray(a_pack), jnp.asarray(b_pack)))
+        res_dev = ktri.batched_tri_solve(
+            jnp.asarray(a_pack), jnp.asarray(b_pack))
         b_bytes = b_pack.nbytes
+    res = np.asarray(res_dev)
     wall = time.perf_counter() - t0
 
     c_blocks = 0
@@ -877,7 +880,20 @@ def dispatch_solve_wave(tasks: list[_Pending], *, kind: str, n: int,
         "c_blocks": int(c_blocks), "wall_s": wall,
         "bytes_packed": int(a_pack.nbytes + b_bytes
                             + res.astype(np.float32).nbytes),
+        # XLA-native triangular ops: no Pallas kernel on either backend
+        **ran_on(res_dev, use_pallas=False, interpret=False),
     }
+
+
+def ran_on(out, *, use_pallas: bool, interpret: bool) -> dict:
+    """Wave-record fields naming how and where a wave's kernel ran.
+
+    ``platform`` is read off the result array itself, so a record cannot
+    claim a device the work never reached.
+    """
+    (platform,) = {d.platform for d in out.devices()}
+    return {"use_pallas": use_pallas, "interpret": interpret,
+            "platform": platform}
 
 
 def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
@@ -944,27 +960,27 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
     order = np.argsort(seg, kind="stable")
     sa, sb, seg = sa[order], sb[order], seg[order]
 
+    _, interpret = kops.resolve(True, interpret)
     t0 = time.perf_counter()
     with tracer.span("kernel.dispatch", track="engine",
                      kernel=kernel, bs=bs,
                      pairs=int(n_pairs), c_blocks=int(n_slots)):
         if kernel == "pairs":
-            c = kops.bsmm_pairs(
+            c_dev = kops.bsmm_pairs(
                 jnp.asarray(a_pack), jnp.asarray(b_pack),
                 jnp.asarray(sa), jnp.asarray(sb),
                 jnp.asarray(seg), cap_c=n_slots, use_pallas=True,
                 interpret=interpret)
-            c = np.asarray(c)
+            c = np.asarray(c_dev)
             padded = n_pairs
         else:
             # host gather feeds the cuBLAS-shaped batch; batched_gemm
             # zero-pads to a block_t multiple internally
-            prods = np.asarray(kops.batched_gemm(
+            c_dev = kops.batched_gemm(
                 jnp.asarray(a_pack[sa]), jnp.asarray(b_pack[sb]),
-                block_t=block_t, use_pallas=True,
-                interpret=interpret))
+                block_t=block_t, use_pallas=True, interpret=interpret)
             c = np.zeros((n_slots, bs, bs), np.float32)
-            np.add.at(c, seg, prods)
+            np.add.at(c, seg, np.asarray(c_dev))
             padded = n_pairs + (-n_pairs) % block_t
     wall = time.perf_counter() - t0
 
@@ -974,6 +990,7 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
         "unique_blocks": len(a_list) + len(b_list),
         "c_blocks": int(n_slots), "wall_s": wall,
         "bytes_packed": int(a_pack.nbytes + b_pack.nbytes + c.nbytes),
+        **ran_on(c_dev, use_pallas=True, interpret=interpret),
     }
     for base, t in zip(slot_base, tasks):
         unpack_blocks(t.out, list(t.out.blocks),

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .blocksparse import enumerate_pairs_flat
 
@@ -197,6 +196,6 @@ def summa_spmm(mesh: Mesh, axes: tuple[str, str], plan: SummaPlan,
         return cb[None], crows[None], ccols[None], n_pairs[None]
 
     spec = P((ax_r, ax_c))
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,) * 6,
-                   out_specs=(spec,) * 4, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 6,
+                       out_specs=(spec,) * 4, check_vma=False)
     return fn(a_blocks, a_rows, a_cols, b_blocks, b_rows, b_cols)

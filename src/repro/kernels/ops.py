@@ -1,16 +1,17 @@
 """Jit'd public wrappers around the Pallas kernels with XLA fallbacks.
 
 ``use_pallas=None`` (default) selects the Pallas kernel on TPU and the XLA
-reference elsewhere; ``interpret=True`` runs the kernel bodies in Python on
-CPU (how kernels are validated in this repo's tests).  The contract of each
-op is defined by kernels/ref.py.
+reference on CPU; ``interpret=None`` compiles the kernel on TPU and runs
+its body in Python on CPU (how kernels are validated in this repo's
+tests).  Any other backend raises: there is no silent fallback that would
+hide which device ran the work.  The contract of each op is defined by
+kernels/ref.py.
 """
 from __future__ import annotations
 
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
 from .batched_gemm import batched_gemm as _batched_gemm_kernel
@@ -18,8 +19,25 @@ from .block_attention import banded_attention as _banded_attention_kernel
 from .bsmm_pairs import bsmm_pairs as _bsmm_pairs_kernel
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve(use_pallas: Optional[bool] = None,
+            interpret: Optional[bool] = None) -> tuple[bool, bool]:
+    """The ``(use_pallas, interpret)`` pair a kernel call will run with.
+
+    ``None`` entries resolve from the default backend: ``tpu`` -> compiled
+    Pallas, ``cpu`` -> XLA reference / interpret mode.  Any other backend
+    raises ``RuntimeError``, so a run can never fall back without saying.
+    """
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"no kernel path for the {backend!r} backend: the Pallas kernels "
+            f"compile for 'tpu' and run interpreted on 'cpu' only")
+    on_tpu = backend == "tpu"
+    if use_pallas is None:
+        use_pallas = on_tpu
+    if interpret is None:
+        interpret = not on_tpu
+    return bool(use_pallas), bool(interpret)
 
 
 def batched_gemm(a: jax.Array, b: jax.Array, *, block_t: int = 8,
@@ -27,16 +45,13 @@ def batched_gemm(a: jax.Array, b: jax.Array, *, block_t: int = 8,
                  interpret: Optional[bool] = None) -> jax.Array:
     """C[p] = A[p] @ B[p]; (P, bs, bs) each.
 
-    ``interpret=None`` auto-selects: compiled on TPU, interpret mode on CPU
-    (so ``use_pallas=True`` exercises the kernel body everywhere).  The
-    kernel zero-pads batches to a multiple of ``block_t`` internally.
+    ``use_pallas=True`` with ``interpret=None`` exercises the kernel body
+    everywhere (compiled on TPU, interpreted on CPU).  The kernel
+    zero-pads batches to a multiple of ``block_t`` internally.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
+    use_pallas, interpret = resolve(use_pallas, interpret)
     if not use_pallas:
         return ref.batched_gemm_ref(a, b)
-    if interpret is None:
-        interpret = not _on_tpu()
     return _batched_gemm_kernel(a, b, block_t=block_t, interpret=interpret)
 
 
@@ -46,23 +61,13 @@ def bsmm_pairs(a_blocks: jax.Array, b_blocks: jax.Array, sa: jax.Array,
                interpret: Optional[bool] = None) -> jax.Array:
     """C[seg[p]] += A[sa[p]] @ B[sb[p]]; seg ascending, cap_c = invalid.
 
-    ``interpret=None`` auto-selects interpret mode off-TPU.
+    Slots no pair reaches come back zero on both paths.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
+    use_pallas, interpret = resolve(use_pallas, interpret)
     if not use_pallas:
         return ref.bsmm_pairs_ref(a_blocks, b_blocks, sa, sb, seg, cap_c)
-    if interpret is None:
-        interpret = not _on_tpu()
-    sa = jnp.clip(sa, 0, a_blocks.shape[0] - 1)
-    sb = jnp.clip(sb, 0, b_blocks.shape[0] - 1)
-    out = _bsmm_pairs_kernel(a_blocks, b_blocks, sa, sb, seg,
-                             cap_c=cap_c, interpret=interpret)
-    # C slots that received no pair were never visited by the kernel: zero
-    # them explicitly (segment_sum in the ref does this implicitly).
-    visited = jnp.zeros((cap_c + 1,), bool).at[jnp.minimum(seg, cap_c)].set(
-        True)[:cap_c]
-    return jnp.where(visited[:, None, None], out, 0)
+    return _bsmm_pairs_kernel(a_blocks, b_blocks, sa, sb, seg,
+                              cap_c=cap_c, interpret=interpret)
 
 
 def banded_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -71,8 +76,7 @@ def banded_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      use_pallas: Optional[bool] = None,
                      interpret: bool = False) -> jax.Array:
     """Sliding-window attention, (H, S, D) -> (H, S, D)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
+    use_pallas, _ = resolve(use_pallas)
     if not use_pallas:
         return ref.banded_attention_ref(q, k, v, window, causal=causal)
     return _banded_attention_kernel(

@@ -33,21 +33,85 @@ Data movement model per wave:
   shipping is rectangular: every device sends the same padded count per
   shift, so this is an upper envelope of fetch).
 
-What is *not* real here: devices are whatever jax exposes (forced host
-devices in CI — ``XLA_FLAGS=--xla_force_host_platform_device_count=8``),
-and wave staging/unpacking still round-trips through the host like the
-parent :class:`~repro.core.engine.PallasEngine` does.  The sharding,
-collectives and per-device counters are real.
+Devices are whatever jax exposes: the four chips of a TPU v5e host (``python
+chip_smoke.py --chips 4`` runs ``A @ B`` at n = 65,536 there, every device
+owning output and the result bitwise equal to the one-chip
+``engine="pallas"`` product) or forced host devices in CPU tests
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8``). What is not
+device-resident yet: wave staging and unpacking still round-trip through the
+host like the parent :class:`~repro.core.engine.PallasEngine` does. The
+sharding, collectives and per-device counters are real.
+
+The default kernel is ``"pairs"``, which gathers operand blocks by DMA.
+``"gemm"`` gathers both operands of every pair and their products into
+``(pairs, bs, bs)`` arrays, 192 KiB per pair at bs = 128: at the n = 65,536
+wave above the TPU compiler refuses it for lack of HBM
+(``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
-from repro.core.engine import PallasEngine, _Pending
+from repro.core.engine import PallasEngine, _Pending, ran_on
 from repro.core.leaf import unpack_blocks
+from repro.kernels import ops as kops
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "kernel", "shifts", "cap_c", "block_t", "use_pallas",
+    "interpret"))
+def mesh_wave(own_pool, sa, sb, seg, sels, *, mesh, kernel: str,
+              shifts: tuple, cap_c: int, block_t: int, use_pallas: bool,
+              interpret: bool):
+    """One device-sharded wave: ring-shift the halos, run the kernel.
+
+    Every array is stacked over the mesh's ``"dev"`` axis: ``own_pool``
+    ``(n_dev, cap_own, bs, bs)`` holds each device's home blocks;
+    ``sels[i]`` ``(n_dev, cnt_i)`` picks the blocks each device sends on
+    ring shift ``shifts[i]``; ``sa``/``sb``/``seg`` ``(n_dev, cap_p)``
+    address the per-device pool (own segment, then one receive segment
+    per shift).  Returns ``(n_dev, cap_c, bs, bs)`` output slots.
+
+    Module-level and jitted on its static signature (mesh, kernel,
+    shifts, caps) plus the argument shapes, so waves of one shape, such as
+    a plan replay, reuse one executable.
+    """
+    n_dev = mesh.shape["dev"]
+
+    def body(own, sa_, sb_, seg_, *sels_):
+        own = own[0]
+        sa1, sb1, seg1 = sa_[0], sb_[0], seg_[0]
+        parts = [own]
+        for shift, sel in zip(shifts, sels_):
+            perm = [(r, (r + shift) % n_dev) for r in range(n_dev)]
+            parts.append(jax.lax.ppermute(own[sel[0]], "dev", perm))
+        pool = jnp.concatenate(parts, axis=0) if len(parts) > 1 \
+            else parts[0]
+        if kernel == "pairs":
+            c = kops.bsmm_pairs(pool, pool, sa1, sb1, seg1, cap_c=cap_c,
+                                use_pallas=use_pallas, interpret=interpret)
+        else:
+            prods = kops.batched_gemm(pool[sa1], pool[sb1], block_t=block_t,
+                                      use_pallas=use_pallas,
+                                      interpret=interpret)
+            prods = jnp.where((seg1 < cap_c)[:, None, None], prods, 0)
+            c = jax.ops.segment_sum(
+                prods.astype(jnp.float32), jnp.minimum(seg1, cap_c),
+                num_segments=cap_c + 1)[:cap_c]
+        return c[None]
+
+    spec = P("dev")
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec,) * (4 + len(sels)),
+                         out_specs=spec, check_vma=False)(
+        own_pool, sa, sb, seg, *sels)
 
 
 class MeshEngine(PallasEngine):
@@ -56,10 +120,12 @@ class MeshEngine(PallasEngine):
     Parameters
     ----------
     n_dev : devices to shard over (default: all visible jax devices).
-    kernel : ``"gemm"`` (batched_gemm + segment_sum scatter, the default)
-        or ``"pairs"`` (the fused bsmm_pairs gather-GEMM-scatter shape).
+    kernel : ``"pairs"`` (the fused bsmm_pairs gather-GEMM-scatter, the
+        default) or ``"gemm"`` (batched_gemm + segment_sum scatter, which
+        materialises the gathered operands; small waves only).
     use_pallas / interpret : forwarded to :mod:`repro.kernels.ops`;
-        ``None`` auto-selects (Pallas on TPU, XLA reference elsewhere).
+        ``None`` auto-selects (compiled Pallas on TPU, the XLA
+        reference on CPU; other backends raise).
     block_t : batch tile of the batched_gemm kernel.
 
     Inherits the deferral machinery, NIL/structure semantics, host-side
@@ -70,7 +136,7 @@ class MeshEngine(PallasEngine):
 
     name = "mesh"
 
-    def __init__(self, n_dev: Optional[int] = None, kernel: str = "gemm",
+    def __init__(self, n_dev: Optional[int] = None, kernel: str = "pairs",
                  interpret: Optional[bool] = None,
                  use_pallas: Optional[bool] = None, block_t: int = 8):
         super().__init__(kernel=kernel, interpret=interpret,
@@ -122,13 +188,6 @@ class MeshEngine(PallasEngine):
     # -- wave execution ------------------------------------------------------
     def _run_group(self, bs: int, tasks: list[_Pending]) -> None:
         """One device-sharded dispatch for every block pair of the wave."""
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
-
-        from repro.kernels import ops as kops
-
         mesh = self._ensure_mesh()
         n_dev = self.n_dev
         bsz = bs * bs * 4               # float32 wire format
@@ -203,19 +262,18 @@ class MeshEngine(PallasEngine):
                     fetched_now += 1
         shifts = sorted(ship)
         cnts = [max(len(lst) for lst in ship[s]) for s in shifts]
+        # pool position of slot sk as seen by device d: the own segment,
+        # then one recv segment per shift at a static offset
+        recv_pos: dict[tuple, int] = {}     # (shift, slot) -> pool pos
         sels = []
+        off = cap_own
         for s, cnt in zip(shifts, cnts):
             sel = np.zeros((n_dev, cnt), np.int32)
             for src in range(n_dev):
                 for i, sk in enumerate(ship[s][src]):
                     sel[src, i] = own_pos[sk]
+                    recv_pos[(s, sk)] = off + i
             sels.append(sel)
-        # pool position of slot sk as seen by device d: the own segment,
-        # then one recv segment per shift at a static offset
-        seg_off = {}
-        off = cap_own
-        for s, cnt in zip(shifts, cnts):
-            seg_off[s] = off
             off += cnt
         pool_len = off
 
@@ -223,8 +281,7 @@ class MeshEngine(PallasEngine):
             h = slot_home[sk]
             if h == d:
                 return own_pos[sk]
-            s = (d - h) % n_dev
-            return seg_off[s] + ship[s][h].index(sk)
+            return recv_pos[((d - h) % n_dev, sk)]
 
         # 5. per-device pair tables (sa/sb into the halo'd pool, seg into
         # the device-local output slots; cap-padded, seg=cap_c invalid)
@@ -259,39 +316,7 @@ class MeshEngine(PallasEngine):
                 sa[d, i], sb[d, i], seg[d, i] = pa, pb, pc
 
         # 6. the sharded dispatch: ring-shift the halos, run the kernel
-        kernel, use_pallas, interpret, block_t = (
-            self.kernel, self.use_pallas, self.interpret, self.block_t)
-
-        def body(own, sa_, sb_, seg_, *sels_):
-            own = own[0]
-            sa1, sb1, seg1 = sa_[0], sb_[0], seg_[0]
-            parts = [own]
-            for shift, sel in zip(shifts, sels_):
-                send = own[sel[0]]
-                perm = [(r, (r + shift) % n_dev) for r in range(n_dev)]
-                parts.append(jax.lax.ppermute(send, "dev", perm))
-            pool = jnp.concatenate(parts, axis=0) if len(parts) > 1 \
-                else parts[0]
-            if kernel == "pairs":
-                c = kops.bsmm_pairs(pool, pool, sa1, sb1, seg1,
-                                    cap_c=cap_c, use_pallas=use_pallas,
-                                    interpret=interpret)
-            else:
-                prods = kops.batched_gemm(pool[sa1], pool[sb1],
-                                          block_t=block_t,
-                                          use_pallas=use_pallas,
-                                          interpret=interpret)
-                prods = jnp.where((seg1 < cap_c)[:, None, None], prods, 0)
-                c = jax.ops.segment_sum(
-                    prods.astype(jnp.float32), jnp.minimum(seg1, cap_c),
-                    num_segments=cap_c + 1)[:cap_c]
-            return c[None]
-
-        spec = P("dev")
-        fn = shard_map(
-            body, mesh=mesh,
-            in_specs=(spec,) * (4 + len(sels)),
-            out_specs=spec, check_rep=False)
+        use_pallas, interpret = kops.resolve(self.use_pallas, self.interpret)
         tr = self.tracer
         if tr.enabled and shifts:
             tr.instant("collective.ppermute", track="engine",
@@ -299,9 +324,12 @@ class MeshEngine(PallasEngine):
                        shipped_blocks=int(sum(len(lst) for s in shifts
                                               for lst in ship[s])),
                        padded_shipped_blocks=int(sum(cnts) * n_dev))
-        with tr.span("kernel.dispatch", track="engine", kernel=kernel,
+        with tr.span("kernel.dispatch", track="engine", kernel=self.kernel,
                      bs=bs, n_dev=n_dev, pairs=int(n_pairs)):
-            c_dev = jax.jit(fn)(own_pool, sa, sb, seg, *sels)
+            c_dev = mesh_wave(own_pool, sa, sb, seg, tuple(sels), mesh=mesh,
+                              kernel=self.kernel, shifts=tuple(shifts),
+                              cap_c=cap_c, block_t=self.block_t,
+                              use_pallas=use_pallas, interpret=interpret)
             c_np = np.asarray(c_dev)
 
         # 7. scatter into the placeholder out leaves; produced blocks are
@@ -320,11 +348,12 @@ class MeshEngine(PallasEngine):
         padded_ship = sum(cnts) * n_dev
         self._collective_bytes += sum(cnts) * bsz   # every device receives
         self._waves.append({
-            "kernel": kernel, "bs": bs, "tasks": nt, "pairs": int(n_pairs),
-            "padded_pairs": int(cap_p * n_dev),
+            "kernel": self.kernel, "bs": bs, "tasks": nt,
+            "pairs": int(n_pairs), "padded_pairs": int(cap_p * n_dev),
             "unique_blocks": len(slot_home), "c_blocks": int(sum(n_out)),
             "wall_s": wall,
             "bytes_packed": int(own_pool.nbytes + c_np.nbytes),
+            **ran_on(c_dev, use_pallas=use_pallas, interpret=interpret),
         })
         self._comm_log.append({
             "bs": bs, "n_dev": n_dev, "tasks": nt, "pairs": int(n_pairs),
@@ -332,6 +361,7 @@ class MeshEngine(PallasEngine):
             "padded_shipped_blocks": int(padded_ship),
             "fetched_blocks": int(fetched_now),
             "pool_len": int(pool_len), "cap_c": int(cap_c),
+            "c_blocks_by_dev": list(n_out),
             "wall_s": wall,
             # this wave's measured per-device counter deltas (exported as
             # Perfetto counter tracks; see obs/export.mesh_stats_events)

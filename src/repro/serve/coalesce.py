@@ -132,6 +132,8 @@ class WaveCoalescer:
                 "wall_s": record["wall_s"] * share,
                 "bytes_packed": int(record["bytes_packed"] * share),
                 "batch_key": list(key), "coalesced": len(parts),
+                **{k: record[k] for k in ("use_pallas", "interpret",
+                                          "platform")},
             })
 
     # -- reporting ------------------------------------------------------------

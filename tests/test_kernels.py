@@ -6,6 +6,7 @@ import pytest
 from _hyp import given, settings, st
 
 from repro.kernels import ops, ref
+from repro.kernels.bsmm_pairs import _in_calls as bsmm_pairs_in_calls
 
 RNG = np.random.default_rng(0)
 
@@ -84,6 +85,24 @@ class TestBsmmPairs:
         out = ops.bsmm_pairs(ab, bb, sa, sb, seg, cap_c=4,
                              use_pallas=True, interpret=True)
         assert np.all(np.asarray(out[1:]) == 0)
+
+    @pytest.mark.parametrize("max_pairs", [4, 7, 30])
+    def test_split_into_calls_is_bitwise_one_call(self, max_pairs):
+        """Pair streams longer than one call's SMEM bound run as several
+        calls over one C buffer; segments cut between calls, invalid
+        pairs at the tail and unvisited slots give bitwise the one-call
+        result."""
+        ab, bb, sa, sb, seg = self._case(8, 8, 6, 30, 8, seed=3)
+        seg = seg.at[-4:].set(6)            # invalid tail (== cap_c)
+        seg = jnp.where(seg == 5, 4, seg)   # last slot unvisited
+        out = bsmm_pairs_in_calls(ab, bb, sa, sb, seg, cap_c=6,
+                                  interpret=True, max_pairs=max_pairs)
+        one = bsmm_pairs_in_calls(ab, bb, sa, sb, seg, cap_c=6,
+                                  interpret=True, max_pairs=64)
+        np.testing.assert_array_equal(out, one)
+        np.testing.assert_allclose(
+            out, ref.bsmm_pairs_ref(ab, bb, sa, sb, seg, 6), atol=1e-4)
+        assert np.all(np.asarray(out[5]) == 0)
 
     def test_bfloat16(self):
         ab, bb, sa, sb, seg = self._case(8, 8, 4, 16, 16, dtype=jnp.bfloat16)

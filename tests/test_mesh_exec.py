@@ -166,6 +166,36 @@ class TestLifecycle:
         st2 = sess.engine_stats()
         assert sum(st2["pushed_bytes"]) > sum(st1["pushed_bytes"])
 
+    @pytest.mark.parametrize("kernel", ["gemm", "pairs"])
+    def test_plan_replay_compiles_nothing(self, kernel):
+        """Waves of one shape reuse one jitted executable: a rebound plan
+        replay triggers no XLA compile."""
+        import jax
+
+        from repro.launch.mesh_exec import MeshEngine
+
+        rng = np.random.default_rng(2)
+        a = rng.standard_normal((N, N)) * 0.1
+        sess = Session(engine=MeshEngine(kernel=kernel), leaf_n=LEAF_N,
+                       bs=BS, lazy=True)
+        X = sess.from_dense(a, name="X")
+        plan = sess.compile(X @ X)
+        plan.run().to_dense()
+        compiles = []
+
+        def on_event(event, duration, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(duration)
+
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        try:
+            a2 = rng.standard_normal((N, N)) * 0.1
+            out = plan.run(X=a2).to_dense()
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_event)
+        np.testing.assert_allclose(out, a2 @ a2, **TOL)
+        assert compiles == []
+
     def test_engine_stats_shape(self):
         a = values_for_mask(banded_mask(N, 5), seed=1)
         sess = Session(engine="mesh", leaf_n=LEAF_N, bs=BS)
