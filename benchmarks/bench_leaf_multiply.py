@@ -103,7 +103,7 @@ def compare_backends(n: int, pattern: str, leaf_n: int, bs: int,
                 "batched_pairs": stats.get("batched_pairs"),
                 "padded_pairs": stats.get("padded_pairs"),
                 "c_blocks": stats.get("c_blocks"),
-                "kernel_wall_s": stats.get("kernel_wall_s"),
+                "dispatch_s": stats.get("dispatch_s"),
                 "bytes_packed": stats.get("bytes_packed"),
             })
         record["backends"][name] = entry

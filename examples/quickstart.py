@@ -57,7 +57,7 @@ def main() -> None:
           f"{st['waves']} fused wave(s); {st['batched_pairs']} block pairs "
           f"batched ({st['padded_pairs'] - st['batched_pairs']} padding), "
           f"{st['bytes_packed'] / 1e6:.2f} MB packed, "
-          f"kernel {st['kernel']} in {st['kernel_wall_s'] * 1e3:.1f} ms")
+          f"kernel {st['kernel']} dispatched in {st['dispatch_s'] * 1e3:.1f} ms")
 
     # --- 3. the TPU engine (jit, static shapes) -------------------------
     ma = block_mask_from_element_mask(np.abs(a) > 0, bs)

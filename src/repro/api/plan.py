@@ -461,7 +461,7 @@ class Plan:
                     (w.get("padded_pairs", 0) - w.get("pairs", 0))
                     / max(w.get("padded_pairs", 0), 1)),
                 "bytes_packed": w.get("bytes_packed"),
-                "wall_s": w.get("wall_s"),
+                "dispatch_s": w.get("dispatch_s"),
             } for w in waves],
             "metrics": [ms.to_dict() for ms in metric_sets],
         }

@@ -40,6 +40,7 @@ padding counts, bytes packed) is recorded in :meth:`PallasEngine.stats`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from typing import Any, Optional
@@ -675,7 +676,7 @@ class PallasEngine(LeafEngine):
             if tr.enabled:
                 with tr.span("engine.wave", track="engine") as sp:
                     self._waves.append(dispatch_solve_wave(
-                        tasks, kind=kind, n=n, bs=bs))
+                        tasks, kind=kind, n=n, bs=bs, tracer=tr))
                     sp.set(**self._wave_span_attrs())
             else:
                 self._waves.append(dispatch_solve_wave(
@@ -689,9 +690,19 @@ class PallasEngine(LeafEngine):
         """Execute every ready host-side fill (add/transpose/scale).
 
         Returns True if anything ran — the progress signal both
-        :meth:`flush` and the coalescer's drain loop use.
+        :meth:`flush` and the coalescer's drain loop use.  Traced, a pass
+        that runs at least one fill is one ``engine.flush.host`` span.
         """
-        progressed = False
+        tr = self.tracer
+        if tr.enabled and any(t.payload.kind in HOST_KINDS and self._ready(t)
+                              for t in self._pending):
+            with tr.span("engine.flush.host", track="engine") as sp:
+                return self._host_pass(sp)
+        return self._host_pass(None)
+
+    def _host_pass(self, span) -> bool:
+        done = {"add": 0, "transpose": 0, "scale": 0}
+        blocks = 0
         rest = []
         for t in self._pending:
             if t.payload.kind in HOST_KINDS and self._ready(t):
@@ -702,11 +713,15 @@ class PallasEngine(LeafEngine):
                 else:
                     self._run_transpose(t)
                 self._unfilled.discard(id(t.out))
-                progressed = True
+                done[t.payload.kind] += 1
+                blocks += len(t.out.blocks)
             else:
                 rest.append(t)
         self._pending = rest
-        return progressed
+        if span is not None:
+            span.set(adds=done["add"], transposes=done["transpose"],
+                     scales=done["scale"], blocks=blocks)
+        return any(done.values())
 
     def commit_tasks(self, tasks: list, wave_record: Optional[dict] = None
                      ) -> None:
@@ -833,14 +848,14 @@ class PallasEngine(LeafEngine):
             "batched_pairs": sum(w["pairs"] for w in self._waves),
             "padded_pairs": sum(w["padded_pairs"] for w in self._waves),
             "c_blocks": sum(w["c_blocks"] for w in self._waves),
-            "kernel_wall_s": sum(w["wall_s"] for w in self._waves),
+            "dispatch_s": sum(w["dispatch_s"] for w in self._waves),
             "bytes_packed": sum(w["bytes_packed"] for w in self._waves),
             "wave_log": list(self._waves),
         }
 
 
 def dispatch_solve_wave(tasks: list[_Pending], *, kind: str, n: int,
-                        bs: int) -> dict:
+                        bs: int, tracer=NOOP) -> dict:
     """One batched triangular-kernel call for every ready solve leaf.
 
     Leaves are densified host-side (symmetric upper storage expands to
@@ -850,22 +865,20 @@ def dispatch_solve_wave(tasks: list[_Pending], *, kind: str, n: int,
     with the same accounting fields as the GEMM waves (``pairs`` counts
     leaves here — one "pair" of dense operands per task).
     """
-    import jax.numpy as jnp
     from repro.kernels import tri as ktri
 
     a_pack = np.stack([t.a_leaf.to_dense() for t in tasks]).astype(np.float32)
+    b_pack = None if kind == "inv_chol" else np.stack(
+        [t.b_leaf.to_dense() for t in tasks]).astype(np.float32)
     t0 = time.perf_counter()
-    if kind == "inv_chol":
-        res_dev = ktri.batched_inv_chol(jnp.asarray(a_pack))
-        b_bytes = 0
-    else:
-        b_pack = np.stack([t.b_leaf.to_dense()
-                           for t in tasks]).astype(np.float32)
-        res_dev = ktri.batched_tri_solve(
-            jnp.asarray(a_pack), jnp.asarray(b_pack))
-        b_bytes = b_pack.nbytes
-    res = np.asarray(res_dev)
-    wall = time.perf_counter() - t0
+    with tracer.span("kernel.dispatch", track="engine", kernel=kind,
+                     bs=bs, pairs=len(tasks)):
+        res_dev, res = _round_trip(
+            tracer, [x for x in (a_pack, b_pack) if x is not None],
+            ktri.batched_inv_chol if kind == "inv_chol"
+            else ktri.batched_tri_solve, pairs=len(tasks))
+    dispatch = time.perf_counter() - t0
+    b_bytes = 0 if b_pack is None else b_pack.nbytes
 
     c_blocks = 0
     for t, x in zip(tasks, res):
@@ -877,12 +890,40 @@ def dispatch_solve_wave(tasks: list[_Pending], *, kind: str, n: int,
     return {
         "kernel": kind, "bs": bs, "tasks": len(tasks),
         "pairs": len(tasks), "padded_pairs": len(tasks),
-        "c_blocks": int(c_blocks), "wall_s": wall,
+        "c_blocks": int(c_blocks), "dispatch_s": dispatch,
         "bytes_packed": int(a_pack.nbytes + b_bytes
                             + res.astype(np.float32).nbytes),
         # XLA-native triangular ops: no Pallas kernel on either backend
         **ran_on(res_dev, use_pallas=False, interpret=False),
     }
+
+
+def _round_trip(tracer, host, call, fetch=np.asarray, **run_attrs):
+    """Upload ``host`` arrays, run ``call`` on them, fetch the result.
+
+    Returns ``(device result, fetch(device result))``, each step in its
+    span: ``kernel.upload`` (``bytes`` sent), ``kernel.run`` (the jitted
+    call, ``run_attrs``) and ``kernel.download`` (``bytes`` read back).
+    Only a recording tracer waits for the device between the steps, so
+    each span times its own work; untraced, the calls into JAX are the
+    asynchronous uploads, the call and the blocking read-back.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    with tracer.span("kernel.upload", track="engine") as sp:
+        operands = [jnp.asarray(x) for x in host]
+        if tracer.enabled:
+            jax.block_until_ready(operands)
+            sp.set(bytes=sum(int(x.nbytes) for x in operands))
+    with tracer.span("kernel.run", track="engine", **run_attrs):
+        out = call(*operands)
+        if tracer.enabled:
+            jax.block_until_ready(out)
+    with tracer.span("kernel.download", track="engine") as sp:
+        res = fetch(out)
+        sp.set(bytes=int(out.nbytes))
+    return out, res
 
 
 def ran_on(out, *, use_pallas: bool, interpret: bool) -> dict:
@@ -911,20 +952,73 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
     argsort on segment id, so every output block accumulates its products
     in the same order regardless of which other tasks share the wave.
     """
-    import jax.numpy as jnp
     from repro.kernels import ops as kops
 
-    # global output slot numbering: task-by-task, structure order
-    slot_base: list[int] = []
-    n_slots = 0
-    for t in tasks:
-        slot_base.append(n_slots)
-        n_slots += len(t.out.blocks)
+    with tracer.span("engine.wave.pack", track="engine") as sp:
+        a_pack, b_pack, sa, sb, seg, n_slots = _pack_wave(tasks)
+        n_pairs = len(seg)
+        sp.set(pairs=int(n_pairs),
+               unique_blocks=len(a_pack) + len(b_pack))
 
-    # operands are packed *uniquely* — one slot per distinct
-    # (leaf, key, transpose) block — and pairs address them through
-    # sa/sb indices, which is exactly the slot-indexed gather the
-    # bsmm_pairs scalar-prefetch kernel is built around
+    _, interpret = kops.resolve(True, interpret)
+    run_attrs = {"pairs": int(n_pairs), "cap_c": int(n_slots)}
+    t0 = time.perf_counter()
+    with tracer.span("kernel.dispatch", track="engine",
+                     kernel=kernel, bs=bs,
+                     pairs=int(n_pairs), c_blocks=int(n_slots)):
+        if kernel == "pairs":
+            c_dev, c = _round_trip(
+                tracer, (a_pack, b_pack, sa, sb, seg),
+                functools.partial(kops.bsmm_pairs, cap_c=n_slots,
+                                  use_pallas=True, interpret=interpret),
+                **run_attrs)
+            padded = n_pairs
+        else:
+            # host gather feeds the cuBLAS-shaped batch (uploaded as it
+            # is gathered); batched_gemm zero-pads to a block_t multiple
+            # internally; the scatter-add joins the read-back
+            def scatter_add(prods_dev):
+                out = np.zeros((n_slots, bs, bs), np.float32)
+                np.add.at(out, seg, np.asarray(prods_dev))
+                return out
+
+            c_dev, c = _round_trip(
+                tracer, (pack[idx] for pack, idx in ((a_pack, sa),
+                                                     (b_pack, sb))),
+                functools.partial(kops.batched_gemm, block_t=block_t,
+                                  use_pallas=True, interpret=interpret),
+                fetch=scatter_add, **run_attrs)
+            padded = n_pairs + (-n_pairs) % block_t
+    dispatch = time.perf_counter() - t0
+
+    record = {
+        "kernel": kernel, "bs": bs, "tasks": len(tasks),
+        "pairs": int(n_pairs), "padded_pairs": int(padded),
+        "unique_blocks": len(a_pack) + len(b_pack),
+        "c_blocks": int(n_slots), "dispatch_s": dispatch,
+        "bytes_packed": int(a_pack.nbytes + b_pack.nbytes + c.nbytes),
+        **ran_on(c_dev, use_pallas=True, interpret=interpret),
+    }
+    with tracer.span("engine.wave.unpack", track="engine",
+                     c_blocks=int(n_slots)):
+        base = 0
+        for t in tasks:
+            keys = list(t.out.blocks)
+            unpack_blocks(t.out, keys, c[base:base + len(keys)])
+            base += len(keys)
+    return record
+
+
+def _pack_wave(tasks: list[_Pending]) -> tuple:
+    """Host packing of one wave: ``(a_pack, b_pack, sa, sb, seg, n_slots)``.
+
+    Output slots are numbered task-by-task in structure order; operands
+    are packed *uniquely* — one slot per distinct (leaf, key, transpose)
+    block — and pairs address them through ``sa``/``sb``, which is
+    exactly the slot-indexed gather the bsmm_pairs scalar-prefetch kernel
+    is built around.  Pairs come back in ascending segment order (the
+    bsmm_pairs accumulation contract), by a stable sort.
+    """
     n_pairs = sum(len(t.pairs) for t in tasks)
     a_slots: dict[tuple, int] = {}
     b_slots: dict[tuple, int] = {}
@@ -945,8 +1039,10 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
     sb = np.empty((n_pairs,), np.int32)
     seg = np.empty((n_pairs,), np.int32)
     p = 0
-    for base, t in zip(slot_base, tasks):
-        key_slot = {key: base + i for i, key in enumerate(t.out.blocks)}
+    n_slots = 0
+    for t in tasks:
+        key_slot = {key: n_slots + i for i, key in enumerate(t.out.blocks)}
+        n_slots += len(t.out.blocks)
         srcs = {"a": t.a_leaf, "b": t.b_leaf}
         for src_a, ka, tra, src_b, kb, trb, out_key in t.pairs:
             sa[p] = slot_of(a_slots, a_list, srcs[src_a], ka, tra)
@@ -956,43 +1052,5 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
     a_pack = np.stack(a_list).astype(np.float32)
     b_pack = np.stack(b_list).astype(np.float32)
 
-    # ascending segment ids (bsmm_pairs accumulation contract)
     order = np.argsort(seg, kind="stable")
-    sa, sb, seg = sa[order], sb[order], seg[order]
-
-    _, interpret = kops.resolve(True, interpret)
-    t0 = time.perf_counter()
-    with tracer.span("kernel.dispatch", track="engine",
-                     kernel=kernel, bs=bs,
-                     pairs=int(n_pairs), c_blocks=int(n_slots)):
-        if kernel == "pairs":
-            c_dev = kops.bsmm_pairs(
-                jnp.asarray(a_pack), jnp.asarray(b_pack),
-                jnp.asarray(sa), jnp.asarray(sb),
-                jnp.asarray(seg), cap_c=n_slots, use_pallas=True,
-                interpret=interpret)
-            c = np.asarray(c_dev)
-            padded = n_pairs
-        else:
-            # host gather feeds the cuBLAS-shaped batch; batched_gemm
-            # zero-pads to a block_t multiple internally
-            c_dev = kops.batched_gemm(
-                jnp.asarray(a_pack[sa]), jnp.asarray(b_pack[sb]),
-                block_t=block_t, use_pallas=True, interpret=interpret)
-            c = np.zeros((n_slots, bs, bs), np.float32)
-            np.add.at(c, seg, np.asarray(c_dev))
-            padded = n_pairs + (-n_pairs) % block_t
-    wall = time.perf_counter() - t0
-
-    record = {
-        "kernel": kernel, "bs": bs, "tasks": len(tasks),
-        "pairs": int(n_pairs), "padded_pairs": int(padded),
-        "unique_blocks": len(a_list) + len(b_list),
-        "c_blocks": int(n_slots), "wall_s": wall,
-        "bytes_packed": int(a_pack.nbytes + b_pack.nbytes + c.nbytes),
-        **ran_on(c_dev, use_pallas=True, interpret=interpret),
-    }
-    for base, t in zip(slot_base, tasks):
-        unpack_blocks(t.out, list(t.out.blocks),
-                      c[base:base + len(t.out.blocks)])
-    return record
+    return a_pack, b_pack, sa[order], sb[order], seg[order], n_slots

@@ -324,6 +324,7 @@ class MeshEngine(PallasEngine):
                        shipped_blocks=int(sum(len(lst) for s in shifts
                                               for lst in ship[s])),
                        padded_shipped_blocks=int(sum(cnts) * n_dev))
+        t_dispatch = time.perf_counter()
         with tr.span("kernel.dispatch", track="engine", kernel=self.kernel,
                      bs=bs, n_dev=n_dev, pairs=int(n_pairs)):
             c_dev = mesh_wave(own_pool, sa, sb, seg, tuple(sels), mesh=mesh,
@@ -331,6 +332,7 @@ class MeshEngine(PallasEngine):
                               cap_c=cap_c, block_t=self.block_t,
                               use_pallas=use_pallas, interpret=interpret)
             c_np = np.asarray(c_dev)
+        dispatch = time.perf_counter() - t_dispatch
 
         # 7. scatter into the placeholder out leaves; produced blocks are
         # now resident on their owner (backed by the retained shard ref)
@@ -351,7 +353,7 @@ class MeshEngine(PallasEngine):
             "kernel": self.kernel, "bs": bs, "tasks": nt,
             "pairs": int(n_pairs), "padded_pairs": int(cap_p * n_dev),
             "unique_blocks": len(slot_home), "c_blocks": int(sum(n_out)),
-            "wall_s": wall,
+            "dispatch_s": dispatch,
             "bytes_packed": int(own_pool.nbytes + c_np.nbytes),
             **ran_on(c_dev, use_pallas=use_pallas, interpret=interpret),
         })

@@ -99,7 +99,7 @@ def mesh_stats_events(stats: dict) -> list[dict]:
     """Trace events of a mesh run: devices as tracks, waves as slices.
 
     Wave slices are laid out sequentially on the measured cumulative
-    wall clock (``wall_s`` per wave).  When the per-wave counter deltas
+    dispatch time (``dispatch_s`` per wave: upload, kernel, read-back).  When the per-wave counter deltas
     are present in ``comm_log`` (``fetched_bytes_by_dev`` etc.), each
     device gets cumulative counter tracks of the measured bytes — the
     Table-1 metric over time.
@@ -115,7 +115,7 @@ def mesh_stats_events(stats: dict) -> list[dict]:
     comm = stats.get("comm_log", [])
     for i, w in enumerate(waves):
         c = comm[i] if i < len(comm) else {}
-        dur = float(w.get("wall_s", 0.0))
+        dur = float(w.get("dispatch_s", 0.0))
         for d in range(n_dev):
             events.append({
                 "name": f"wave {i} (bs={w.get('bs')})", "ph": "X",

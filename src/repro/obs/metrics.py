@@ -185,7 +185,7 @@ def from_engine_stats(stats: dict) -> MetricSet:
         ms.add("batched_pairs", "pairs", stats["batched_pairs"])
         ms.add("padded_pairs", "pairs", stats["padded_pairs"])
         ms.add("c_blocks", "blocks", stats["c_blocks"])
-        ms.add("kernel_wall_s", "s", stats["kernel_wall_s"])
+        ms.add("dispatch_s", "s", stats["dispatch_s"])
         ms.add("bytes_packed", "B", stats["bytes_packed"])
     # mesh executor: measured per-device communication counters
     for name, unit in (("fetched_bytes", "B"), ("fetched_blocks", "blocks"),

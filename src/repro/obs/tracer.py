@@ -9,23 +9,39 @@ on) and a nesting depth; the taxonomy threaded through the repo is::
       plan.rebind / plan.replay     api/plan.py      run sub-phases
     qt.multiply / qt.from_dense ...  core/multiply.py, core/quadtree.py
     engine.flush                     core/tasks.py    deferred-wave drain
+      engine.flush.host             core/engine.py   one pass of host fills
       engine.wave                   core/engine.py   one cross-leaf batch
+        engine.wave.pack            core/engine.py   slots, pair loop, stack
         kernel.dispatch             core/engine.py   the fused kernel call
+          kernel.upload             core/engine.py   host -> device operands
+          kernel.run                core/engine.py   the jitted kernel
+          kernel.download           core/engine.py   device -> host result
+        engine.wave.unpack          core/engine.py   result blocks to leaves
         collective.ppermute         launch/mesh_exec ring-shift shipments
 
 Tracing is **off by default**: every instrumented call site holds a
 :data:`NOOP` tracer whose :meth:`~NoopTracer.span` returns a shared,
 stateless context manager — no allocation beyond the argument dict, no
 timing calls, no growth.  The no-op path changes *nothing* observable
-(task graph, schedule, counters); ``Session(trace=True)`` or
-``Session.tracing()`` swaps in a recording :class:`Tracer`.
+(task graph, schedule, counters, the calls made into JAX);
+``Session(trace=True)`` or ``Session.tracing()`` swaps in a recording
+:class:`Tracer`.
+
+A recording tracer mirrors every span onto the profiler's clock: it
+holds a ``jax.profiler.TraceAnnotation`` of the span's name open while
+the span is open (an instant is a zero-length one), so under
+``jax.profiler`` the spans sit on the trace's host plane beside the
+device's operations.  The ``perf_counter`` records are kept as they
+are.  Where a span has to time device work (``kernel.upload``,
+``kernel.run``), the call site waits for the device with
+``block_until_ready`` only when ``tracer.enabled``.
 
 Design constraints (enforced by tests/test_obs.py and
 benchmarks/bench_profile_overhead.py):
 
 * spans are **coarse** — per plan run, per simulator phase, per engine
-  wave; never per task — so the recording overhead stays < 3% on a
-  registration-bound workload;
+  wave or flush pass; never per task — so the recording overhead stays
+  < 3% on a registration-bound workload;
 * instrumentation is purely additive: it never touches RNG state,
   registration order, or chunk contents;
 * span records are plain data (name, t0, t1, track, depth, attrs) so
@@ -63,7 +79,7 @@ class Span:
 class _LiveSpan:
     """An open span (the ``with tracer.span(...)`` handle)."""
 
-    __slots__ = ("_tr", "name", "track", "attrs", "_t0", "_depth")
+    __slots__ = ("_tr", "name", "track", "attrs", "_t0", "_depth", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, track: str, attrs: dict):
         self._tr = tr
@@ -77,6 +93,8 @@ class _LiveSpan:
         return self
 
     def __enter__(self) -> "_LiveSpan":
+        self._ann = self._tr._annotation(self.name)
+        self._ann.__enter__()
         self._depth = len(self._tr._stack)
         self._tr._stack.append(self)
         self._t0 = time.perf_counter()
@@ -89,6 +107,7 @@ class _LiveSpan:
         tr.spans.append(Span(self.name, self._t0 - tr.epoch,
                              t1 - tr.epoch, self.track, self._depth,
                              self.attrs))
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -106,11 +125,18 @@ class Tracer:
     Spans close inner-first; :meth:`ordered` returns them sorted by start
     time (the order exporters want).  ``epoch`` is the perf_counter value
     at construction, so all ``t0``/``t1`` are small relative offsets.
+    Each span and instant is mirrored as a ``jax.profiler``
+    ``TraceAnnotation`` of its name (see the module docstring).
     """
 
     enabled = True
 
     def __init__(self):
+        # imported here, not at module level: ``import repro`` stays
+        # free of JAX for processes that only simulate
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
         self.spans: list[Span] = []
         self._stack: list[_LiveSpan] = []
         self.epoch = time.perf_counter()
@@ -121,7 +147,8 @@ class Tracer:
 
     def instant(self, name: str, track: str = "main", **attrs) -> None:
         """Record a zero-duration marker (Perfetto instant event)."""
-        t = time.perf_counter() - self.epoch
+        with self._annotation(name):
+            t = time.perf_counter() - self.epoch
         self.spans.append(Span(name, t, t, track, len(self._stack), attrs))
 
     def ordered(self) -> list[Span]:

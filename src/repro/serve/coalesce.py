@@ -129,7 +129,7 @@ class WaveCoalescer:
                 "kernel": kernel, "bs": bs, "tasks": len(tasks),
                 "pairs": int(pe_pairs), "padded_pairs": int(pe_pairs),
                 "c_blocks": sum(len(t.out.blocks) for t in tasks),
-                "wall_s": record["wall_s"] * share,
+                "dispatch_s": record["dispatch_s"] * share,
                 "bytes_packed": int(record["bytes_packed"] * share),
                 "batch_key": list(key), "coalesced": len(parts),
                 **{k: record[k] for k in ("use_pallas", "interpret",

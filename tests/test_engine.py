@@ -212,7 +212,7 @@ class TestGraphInvariance:
             # every structural pair ran in a batched wave, exactly once
             assert st_["batched_pairs"] == total_flops(g) / (2.0 * bs ** 3)
             assert st_["padded_pairs"] >= st_["batched_pairs"]
-            assert st_["kernel_wall_s"] > 0.0
+            assert st_["dispatch_s"] > 0.0
 
     def test_cluster_sim_equivalent_across_backends(self):
         """Same task graph + flops => same simulated schedule; makespans

@@ -121,6 +121,178 @@ class TestSessionSpans:
         assert sess.tracer is NOOP
 
 
+def _replayed(product, trace=True):
+    """A compiled X @ X or X.sym_square() on the pallas engine, run once
+    and replayed once with tracing on only for the replay (the shape of
+    one benchmark op); returns the session and the replay's tracer."""
+    a = _banded()
+    a = (a + a.T) / 2
+    sess = Session(engine="pallas", lazy=True, leaf_n=16, bs=8)
+    X = sess.from_dense(a, upper=product == "sym_square", name="X")
+    plan = sess.compile(X.sym_square() if product == "sym_square"
+                        else X @ X)
+    plan.run()
+    sess.flush()
+    tr = Tracer() if trace else NOOP
+    with sess.tracing(tr):
+        plan.run(X=X, flush=False)
+        sess.flush()
+    return sess, tr
+
+
+def _parents(tr) -> list:
+    """``(name, parent name or None)`` of every span, by nesting."""
+    out, stack = [], []
+    for s in sorted(tr.spans, key=lambda s: (s.t0, s.depth)):
+        while stack and stack[-1].depth >= s.depth:
+            stack.pop()
+        out.append((s.name, stack[-1].name if stack else None))
+        stack.append(s)
+    return out
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("product", ["matmul", "sym_square"])
+class TestWaveSpans:
+    """The spans inside a wave: pack, host fill, upload, kernel,
+    download, unpack — under the parents the benchmark's readers
+    assume, with byte counters that match what crossed the bus."""
+
+    NEW = {"engine.wave.pack": "engine.wave",
+           "engine.wave.unpack": "engine.wave",
+           "engine.flush.host": "engine.flush",
+           "kernel.upload": "kernel.dispatch",
+           "kernel.run": "kernel.dispatch",
+           "kernel.download": "kernel.dispatch"}
+
+    def test_each_new_span_sits_under_its_parent(self, product):
+        _, tr = _replayed(product)
+        pairs = _parents(tr)
+        seen = {name for name, _ in pairs}
+        assert set(self.NEW) <= seen
+        for name, parent in pairs:
+            if name in self.NEW:
+                assert parent == self.NEW[name], (name, parent)
+        pack, = tr.find("engine.wave.pack")
+        assert pack.attrs["pairs"] > 0 and pack.attrs["unique_blocks"] > 0
+        run, = tr.find("kernel.run")
+        assert run.attrs["pairs"] == pack.attrs["pairs"]
+        unpack, = tr.find("engine.wave.unpack")
+        assert run.attrs["cap_c"] == unpack.attrs["c_blocks"] > 0
+        host = tr.find("engine.flush.host")
+        assert all(h.attrs["adds"] + h.attrs["transposes"]
+                   + h.attrs["scales"] > 0 for h in host)
+        assert sum(h.attrs["blocks"] for h in host) > 0
+
+    def test_byte_counters_equal_the_arrays_moved(self, product,
+                                                  monkeypatch):
+        from repro.core import engine
+
+        packed = []
+        pack = engine._pack_wave
+
+        def spy(tasks):
+            out = pack(tasks)
+            packed.append(out)
+            return out
+
+        monkeypatch.setattr(engine, "_pack_wave", spy)
+        _, tr = _replayed(product)
+        (a_pack, b_pack, sa, sb, seg, n_slots), = packed[-1:]
+        up, = tr.find("kernel.upload")
+        down, = tr.find("kernel.download")
+        assert up.attrs["bytes"] == sum(
+            x.nbytes for x in (a_pack, b_pack, sa, sb, seg))
+        assert down.attrs["bytes"] == n_slots * 8 * 8 * 4
+
+    def test_existing_layer_sums_unchanged_by_the_children(self, product):
+        from benchmarks.chip.harness import Window
+
+        _, tr = _replayed(product)
+        spans = list(tr.spans)
+        old = [s for s in spans if s.name not in self.NEW]
+        assert len(old) < len(spans)
+
+        def layers(sp):
+            w = Window(ops=1, window_s=1.0, compiles=0, spans=sp)
+            return (w.self_s(("engine.flush", "engine.wave")),
+                    w.total_s(("kernel.dispatch",)),
+                    w.self_s(("plan.", "qt.")))
+
+        for new, before in zip(layers(spans), layers(old)):
+            assert new == pytest.approx(before, rel=1e-9, abs=1e-12)
+
+    def test_untraced_records_nothing_and_never_syncs(self, product,
+                                                      monkeypatch):
+        import jax
+
+        calls = []
+        sync = jax.block_until_ready
+
+        def counting(x):
+            calls.append(1)
+            return sync(x)
+
+        monkeypatch.setattr(jax, "block_until_ready", counting)
+        sess, tr = _replayed(product, trace=False)
+        assert tr is NOOP and NOOP.spans == () and sess.tracer is NOOP
+        assert calls == []
+        _replayed(product)
+        assert len(calls) >= 2      # the patch is live: traced runs sync
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("path", ["gemm", "inv_chol"])
+def test_round_trip_spans_on_the_other_dispatch_paths(path):
+    """The batched_gemm wave and the triangular solve wave split their
+    dispatch into the same three children, with the same counters."""
+    from repro.core.engine import PallasEngine
+
+    a = _banded()
+    spd = a @ a.T + 64 * np.eye(64)
+    sess = Session(engine=PallasEngine(kernel="gemm") if path == "gemm"
+                   else "pallas", leaf_n=16, bs=8)
+    with sess.tracing() as tr:
+        if path == "gemm":
+            x = sess.from_dense(a)
+            got, want = (x @ x).to_dense(), a @ a
+        else:
+            z = sess.from_dense(spd, upper=True).inv_chol().to_dense()
+            got, want = z.T @ spd @ z, np.eye(64)
+    np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-4)
+    pairs = _parents(tr)
+    kids = [name for name, parent in pairs if parent == "kernel.dispatch"]
+    assert kids and set(kids) == {"kernel.upload", "kernel.run",
+                                  "kernel.download"}
+    assert all(s.attrs["bytes"] > 0 for s in tr.spans
+               if s.name in ("kernel.upload", "kernel.download"))
+    assert any(s.attrs["kernel"] == path
+               for s in tr.find("kernel.dispatch"))
+
+
+@pytest.mark.pallas
+def test_program_spans_reach_the_profiler_trace(tmp_path):
+    """Under jax.profiler, each recording span is a host-plane event of
+    the same name, on the profiler's clock."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _, tr = _replayed("matmul")
+        tr.instant("probe.instant")
+    finally:
+        jax.profiler.stop_trace()
+    found, = list(tmp_path.rglob("*.xplane.pb"))
+    pd = ProfileData.from_serialized_xspace(found.read_bytes())
+    host = {e.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+    assert {s.name for s in tr.spans} <= host
+    assert {"plan.run", "engine.wave.pack", "kernel.upload", "kernel.run",
+            "kernel.download", "engine.wave.unpack",
+            "engine.flush.host", "probe.instant"} <= host
+
+
 class TestNoopInert:
     """Tracing off vs on: identical task program and schedule."""
 
@@ -277,7 +449,7 @@ class TestExport:
         st = {"n_dev": 2,
               "wave_log": [{"kernel": "k", "bs": 8, "tasks": 3,
                             "pairs": 5, "padded_pairs": 6, "c_blocks": 4,
-                            "wall_s": 0.25}] * 2,
+                            "dispatch_s": 0.25}] * 2,
               "comm_log": [
                   {"fetched_bytes_by_dev": [256, 0],
                    "pushed_bytes_by_dev": [0, 512],
