@@ -947,6 +947,11 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
     dispatch.  Fills each task's output leaf in place and returns the wave
     record (the caller appends it to the owning engine's wave log).
 
+    Both sides of every pair read one operand table (``_pack_wave``): it
+    is uploaded once and passed as both ``a_blocks`` and ``b_blocks`` of
+    ``bsmm_pairs``; the ``gemm`` path gathers ``table[sa]`` and
+    ``table[sb]`` from it.
+
     Numerical identity with per-engine dispatch: output slots are numbered
     task-by-task in structure order and pairs are sorted by a *stable*
     argsort on segment id, so every output block accumulates its products
@@ -955,10 +960,10 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
     from repro.kernels import ops as kops
 
     with tracer.span("engine.wave.pack", track="engine") as sp:
-        a_pack, b_pack, sa, sb, seg, n_slots = _pack_wave(tasks)
+        table, sa, sb, seg, n_slots, shared = _pack_wave(tasks)
         n_pairs = len(seg)
-        sp.set(pairs=int(n_pairs),
-               unique_blocks=len(a_pack) + len(b_pack))
+        sp.set(pairs=int(n_pairs), unique_blocks=len(table),
+               shared_blocks=shared)
 
     _, interpret = kops.resolve(True, interpret)
     run_attrs = {"pairs": int(n_pairs), "cap_c": int(n_slots)}
@@ -967,11 +972,13 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
                      kernel=kernel, bs=bs,
                      pairs=int(n_pairs), c_blocks=int(n_slots)):
         if kernel == "pairs":
-            c_dev, c = _round_trip(
-                tracer, (a_pack, b_pack, sa, sb, seg),
-                functools.partial(kops.bsmm_pairs, cap_c=n_slots,
-                                  use_pallas=True, interpret=interpret),
-                **run_attrs)
+            def pairs_call(blocks, *index):
+                return kops.bsmm_pairs(blocks, blocks, *index,
+                                       cap_c=n_slots, use_pallas=True,
+                                       interpret=interpret)
+
+            c_dev, c = _round_trip(tracer, (table, sa, sb, seg),
+                                   pairs_call, **run_attrs)
             padded = n_pairs
         else:
             # host gather feeds the cuBLAS-shaped batch (uploaded as it
@@ -983,8 +990,7 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
                 return out
 
             c_dev, c = _round_trip(
-                tracer, (pack[idx] for pack, idx in ((a_pack, sa),
-                                                     (b_pack, sb))),
+                tracer, (table[idx] for idx in (sa, sb)),
                 functools.partial(kops.batched_gemm, block_t=block_t,
                                   use_pallas=True, interpret=interpret),
                 fetch=scatter_add, **run_attrs)
@@ -994,9 +1000,9 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
     record = {
         "kernel": kernel, "bs": bs, "tasks": len(tasks),
         "pairs": int(n_pairs), "padded_pairs": int(padded),
-        "unique_blocks": len(a_pack) + len(b_pack),
+        "unique_blocks": len(table), "shared_blocks": shared,
         "c_blocks": int(n_slots), "dispatch_s": dispatch,
-        "bytes_packed": int(a_pack.nbytes + b_pack.nbytes + c.nbytes),
+        "bytes_packed": int(table.nbytes + c.nbytes),
         **ran_on(c_dev, use_pallas=True, interpret=interpret),
     }
     with tracer.span("engine.wave.unpack", track="engine",
@@ -1010,29 +1016,31 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
 
 
 def _pack_wave(tasks: list[_Pending]) -> tuple:
-    """Host packing of one wave: ``(a_pack, b_pack, sa, sb, seg, n_slots)``.
+    """Host packing of one wave: ``(table, sa, sb, seg, n_slots, shared)``.
 
-    Output slots are numbered task-by-task in structure order; operands
-    are packed *uniquely* — one slot per distinct (leaf, key, transpose)
-    block — and pairs address them through ``sa``/``sb``, which is
-    exactly the slot-indexed gather the bsmm_pairs scalar-prefetch kernel
-    is built around.  Pairs come back in ascending segment order (the
-    bsmm_pairs accumulation contract), by a stable sort.
+    Output slots are numbered task-by-task in structure order.  Operands
+    are packed *uniquely* into one float32 ``table`` that both sides
+    share — one slot per distinct (leaf, key, transpose) block, so a block
+    that is an A operand of one pair and a B operand of another is packed
+    once — and pairs address it through ``sa``/``sb``, which is exactly
+    the slot-indexed gather the bsmm_pairs scalar-prefetch kernel is built
+    around.  Each slot is filled by a cast on copy from its leaf block
+    (the rounding of ``astype``, with no float64 staging); ``shared``
+    counts the slots both sides read.  Pairs come back in ascending
+    segment order (the bsmm_pairs accumulation contract), by a stable
+    sort.
     """
     n_pairs = sum(len(t.pairs) for t in tasks)
-    a_slots: dict[tuple, int] = {}
-    b_slots: dict[tuple, int] = {}
-    a_list: list[np.ndarray] = []
-    b_list: list[np.ndarray] = []
+    slots: dict[tuple, int] = {}
+    blocks: list[np.ndarray] = []
 
-    def slot_of(slots, lst, leaf, key, tr):
+    def slot_of(leaf, key, tr):
         sk = (id(leaf), key, tr)
         s = slots.get(sk)
         if s is None:
-            s = len(lst)
-            slots[sk] = s
+            s = slots[sk] = len(blocks)
             blk = leaf.blocks[key]
-            lst.append(blk.T if tr else blk)
+            blocks.append(blk.T if tr else blk)
         return s
 
     sa = np.empty((n_pairs,), np.int32)
@@ -1045,12 +1053,14 @@ def _pack_wave(tasks: list[_Pending]) -> tuple:
         n_slots += len(t.out.blocks)
         srcs = {"a": t.a_leaf, "b": t.b_leaf}
         for src_a, ka, tra, src_b, kb, trb, out_key in t.pairs:
-            sa[p] = slot_of(a_slots, a_list, srcs[src_a], ka, tra)
-            sb[p] = slot_of(b_slots, b_list, srcs[src_b], kb, trb)
+            sa[p] = slot_of(srcs[src_a], ka, tra)
+            sb[p] = slot_of(srcs[src_b], kb, trb)
             seg[p] = key_slot[out_key]
             p += 1
-    a_pack = np.stack(a_list).astype(np.float32)
-    b_pack = np.stack(b_list).astype(np.float32)
+    table = np.empty((len(blocks),) + blocks[0].shape, np.float32)
+    for s, blk in enumerate(blocks):
+        table[s] = blk
+    shared = len(np.intersect1d(sa, sb))
 
     order = np.argsort(seg, kind="stable")
-    return a_pack, b_pack, sa[order], sb[order], seg[order], n_slots
+    return table, sa[order], sb[order], seg[order], n_slots, shared

@@ -13,7 +13,10 @@ per plan.  :class:`WaveCoalescer` instead:
 2. merges groups with equal keys across engines,
 3. packs each merged group through the same
    :func:`~repro.core.engine.dispatch_packed_wave` the engines use
-   themselves — one kernel call per key per round — and
+   themselves — one kernel call per key per round, whose operands of
+   every engine and both sides share one float32 table (slots are keyed
+   by ``id(leaf)``, so blocks of different engines never share a slot)
+   — and
 4. commits each engine's share back so its wave log and pending set stay
    consistent.
 

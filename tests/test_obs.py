@@ -198,11 +198,12 @@ class TestWaveSpans:
 
         monkeypatch.setattr(engine, "_pack_wave", spy)
         _, tr = _replayed(product)
-        (a_pack, b_pack, sa, sb, seg, n_slots), = packed[-1:]
+        (table, sa, sb, seg, n_slots, _), = packed[-1:]
         up, = tr.find("kernel.upload")
         down, = tr.find("kernel.download")
+        # the operand table serves both sides and goes up once
         assert up.attrs["bytes"] == sum(
-            x.nbytes for x in (a_pack, b_pack, sa, sb, seg))
+            x.nbytes for x in (table, sa, sb, seg))
         assert down.attrs["bytes"] == n_slots * 8 * 8 * 4
 
     def test_existing_layer_sums_unchanged_by_the_children(self, product):
