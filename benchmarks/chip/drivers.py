@@ -8,7 +8,12 @@ produced with the float64 reference in ``check``.  ``control`` puts the
 lower-precision control in the program's place for the same comparison
 (``control.py`` reads it; the benchmark's runs never do).
 
-Drivers:
+A driver is found by name: one of :data:`DRIVERS`, or else the class
+``Driver`` of ``drive/<driver>.py``, so that a new driver is a new file.
+:func:`make_driver` sets the driver's ``chips`` to the cell's chip count
+after building it; a driver that runs on one device may ignore it.
+
+Built-in drivers:
 
 * ``plan_replay`` — a compiled ``Plan`` of the square of an ``overlap3d``
   configuration's matrix, replayed with rebound value sets; one op is
@@ -23,11 +28,15 @@ benchmark loads without it.
 from __future__ import annotations
 
 import contextlib
+import importlib
+import pathlib
 import time
 
 from . import data, reference
 
-__all__ = ["DRIVERS", "make_driver"]
+__all__ = ["DRIVERS", "driver_class", "make_driver"]
+
+DRIVE = pathlib.Path(__file__).resolve().parent / "drive"
 
 
 def annotate(label: str):
@@ -158,10 +167,19 @@ class PlanReplay:
 DRIVERS = {"plan_replay": PlanReplay}
 
 
-def make_driver(cfg: dict, traffic: dict, seed: int, tracer=None):
-    try:
-        cls = DRIVERS[traffic["driver"]]
-    except KeyError:
-        raise ValueError(f"unknown traffic driver {traffic['driver']!r}; "
-                         f"known: {sorted(DRIVERS)}") from None
-    return cls(cfg, traffic, seed, tracer)
+def driver_class(name: str):
+    """The built-in driver ``name``, else ``Driver`` of ``drive/<name>.py``."""
+    if name in DRIVERS:
+        return DRIVERS[name]
+    if (DRIVE / f"{name}.py").is_file():
+        return importlib.import_module(f"{__package__}.drive.{name}").Driver
+    files = sorted(p.stem for p in DRIVE.glob("*.py") if p.stem != "__init__")
+    raise ValueError(f"unknown traffic driver {name!r}; built in: "
+                     f"{sorted(DRIVERS)}; in {DRIVE.name}/: {files}")
+
+
+def make_driver(cfg: dict, traffic: dict, seed: int, tracer=None,
+                chips: int = 1):
+    driver = driver_class(traffic["driver"])(cfg, traffic, seed, tracer)
+    driver.chips = chips
+    return driver

@@ -1,7 +1,8 @@
 """One run of one cell: set up, measure a window, check, report.
 
 Everything a cell needs is found by name: its entry in ``BENCHMARK.json``,
-``configs/<config>.json``, ``traffic/<traffic>.json`` and, for each
+``configs/<config>.json``, ``traffic/<traffic>.json``, the traffic's
+driver (built into ``drivers.py`` or ``drive/<driver>.py``) and, for each
 per-layer metric, ``metrics/<metric>.py`` or, where there is none, the
 reader of its layer's quantity, ``metrics/<metric without its cell
 suffix>.py`` (``api_ms.replay`` falls back to ``api_ms.py``).
@@ -220,7 +221,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if trace:
         from repro.obs.tracer import Tracer
         tracer = Tracer()
-    driver = drivers.make_driver(cell.config, cell.traffic, seed, tracer)
+    driver = drivers.make_driver(cell.config, cell.traffic, seed, tracer,
+                                 chips=cell.chips)
     with count_compiles() as setup_compiles:
         driver.setup()
     gc.collect()                # set-up's garbage is set-up's cost

@@ -1,6 +1,6 @@
 """engine layer (core/engine.py): self time of the program's
 ``engine.wave.pack`` spans (output slot numbering, the pair loop, the
-operand stacks and the segment sort of each wave), ms per op."""
+wave's one float32 operand table and the segment sort), ms per op."""
 
 
 def read(w):
