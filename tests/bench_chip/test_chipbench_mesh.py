@@ -2,8 +2,8 @@
 
 ``drive/mesh_replay.py`` is found by its file name, with no entry in
 ``drivers.DRIVERS``.  The cell ``overlap3d-64k.mesh-replay`` is built from
-its files (``mesh_cell.py``; ``BENCHMARK.json`` does not name it yet) and
-runs end to end through ``harness.run_cell``
+its files (``mesh_cell.py``, with its ``BENCHMARK.json`` entry's metrics
+once that file names it) and runs end to end through ``harness.run_cell``
 at a tiny size, in this process on its one host device and in a
 subprocess on four forced host devices (``mesh_cell.py``), untraced,
 traced and with the control in the program's place; with its timed path
@@ -27,9 +27,10 @@ import mesh_cell  # noqa: E402
 from benchmarks.chip import drivers, harness, xtrace  # noqa: E402
 from benchmarks.chip.drive import mesh_replay  # noqa: E402
 
-E2E = set(mesh_cell.END_TO_END)
+_end_to_end, _per_layer = mesh_cell.metric_lists()
+E2E = {m["name"] for m in _end_to_end}
 # what a CPU trace holds: no device plane, so no kernel or collective time
-TRACED = set(mesh_cell.PER_LAYER) - {
+TRACED = {m["name"] for m in _per_layer} - {
     "mesh_wave_ms.mesh", "mesh_wave_roofline.mesh", "ppermute_ms.mesh"}
 
 
@@ -239,8 +240,10 @@ def test_mesh_readers_read_nothing_without_their_sources():
 
 def test_mesh_cell_files_and_readers():
     """The cell's configuration is the 32k one at 40^3 particles, its
-    traffic names the driver found by name, and every metric it would
-    report has a reader; ``BENCHMARK.json`` does not name it yet."""
+    traffic names the driver found by name and an end-to-end metric that
+    ``BENCHMARK.json`` defines, and every metric it reports has a reader;
+    where ``BENCHMARK.json`` names the cell, its entry names these files
+    on four chips."""
     cfg = harness.load_json(mesh_cell.CONFIG)
     base = harness.load_cell("overlap3d-32k.replay").config
     assert set(cfg) == set(base)
@@ -249,9 +252,15 @@ def test_mesh_cell_files_and_readers():
     assert (cfg["n_per_dim"], cfg["n"], cfg["leaf_n"], cfg["bs"]) == \
         (40, 65536, 1024, 128)
     cell = mesh_cell.tiny_cell(4)
-    assert cell.traffic["driver"] == "mesh_replay"
-    assert cell.traffic["op_metric"] in mesh_cell.END_TO_END
-    for name in mesh_cell.PER_LAYER:
-        assert callable(harness.load_reader(name))
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    assert mesh_cell.CELL not in {w["name"] for w in bench["workloads"]}
+    assert cell.traffic["driver"] == "mesh_replay"
+    assert cell.traffic["op_metric"] in E2E
+    assert cell.traffic["op_metric"] in {m["name"]
+                                         for m in bench["end_to_end"]}
+    for m in cell.per_layer:
+        assert callable(harness.load_reader(m["name"]))
+    # once admitted, the cell's per-layer metrics above are its entry's
+    entry = {w["name"]: w for w in bench["workloads"]}.get(mesh_cell.CELL)
+    if entry is not None:
+        assert (entry["config"], entry["traffic"], entry["chips"]) == \
+            ("overlap3d-64k", "mesh-replay", 4)
