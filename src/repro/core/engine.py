@@ -61,6 +61,9 @@ HOST_KINDS = ("add", "transpose", "scale")
 #: (kernels/tri.py) — their own wave family, never mixed into GEMM waves
 SOLVE_KINDS = ("tri_solve", "inv_chol")
 
+#: leaf-payload kinds a GEMM wave computes (their pairs go to the kernel)
+KERNEL_KINDS = ("multiply", "sym_square", "syrk", "sym_multiply")
+
 
 @dataclasses.dataclass(frozen=True)
 class LeafPayload:
@@ -141,6 +144,14 @@ class LeafEngine:
         rebind hooks) can flush *only when their target is actually
         entangled with pending work* — leaving unrelated deferred waves
         intact for cross-plan coalescing (DESIGN.md §9).
+        """
+        return False
+
+    def is_unfilled(self, leaf) -> bool:
+        """Whether a flushed leaf's numbers were never computed.
+
+        Immediate backends fill every leaf they make; the Pallas engine
+        leaves the partials it folds into a root's wave unfilled for good.
         """
         return False
 
@@ -397,6 +408,10 @@ class _Pending:
     a_leaf: LeafMatrix
     b_leaf: Optional[LeafMatrix]
     pairs: Optional[list] = None    # multiply kinds only
+    # folded product (fold_roots): nid of the root add whose output leaf
+    # ``out`` now is; the kernel sums this task's pairs into it, and a
+    # folded add rides its root's wave without running
+    root: Optional[int] = None
 
 
 class PallasEngine(LeafEngine):
@@ -420,9 +435,23 @@ class PallasEngine(LeafEngine):
                every leaf task against bsmm.compute_c_structure (the boolean
                matmul the TPU path uses).  Costs one eager JAX call per leaf
                task; meant for tests.
+
+    Partial sums in the kernel: the leaf-level add tree of a product
+    (Algorithm 1's ``C_mn = A_m0 B_0n + A_m1 B_1n`` at every level) is
+    folded into the wave that computes its partials.  Every multiply
+    under a root add writes the root's output leaf, so all partials of one
+    output block share one wave segment and ``bsmm_pairs`` sums them in
+    VMEM; the folded adds never run on the host.  Which adds fold is
+    decided once per task (:func:`fold_roots`, at the first flush that
+    sees it) and reused by every replay.  The partial leaves under a root
+    stay unfilled for good: a pending task that read one would never be
+    ready, so the flush raises instead of reading zeros.
     """
 
     name = "pallas"
+    # fold products' add trees into their waves (the mesh executor splits
+    # a wave's tasks across chips, so it keeps per-task outputs)
+    _FOLD_SUMS = True
 
     @property
     def tracer(self):
@@ -441,6 +470,10 @@ class PallasEngine(LeafEngine):
         self._pending: list[_Pending] = []
         self._unfilled: set[int] = set()     # id() of placeholder out leaves
         self._waves: list[dict] = []
+        # fold decisions (fold_roots): the nids decided so far, and the
+        # root add of every folded nid
+        self._decided: set[int] = set()
+        self._root_of: dict[int, int] = {}
         self._graph = None                   # bound CTGraph (one per engine)
 
     # -- registration-time: structure only ----------------------------------
@@ -550,6 +583,27 @@ class PallasEngine(LeafEngine):
         self._pending.append(entry)
         self._unfilled.add(id(entry.out))
 
+    def _fold_fresh(self) -> None:
+        """Decide the fold of every pending task not decided before.
+
+        Runs before any wave or host pass can fill a fresh task's output;
+        the decision is kept per nid, so a replay (:meth:`reexecute`)
+        applies it without walking the graph again.
+        """
+        fresh = [t for t in self._pending if t.nid not in self._decided]
+        if not fresh:
+            return
+        self._decided.update(t.nid for t in fresh)
+        if not self._FOLD_SUMS:
+            return
+        cand = {t.nid: t for t in fresh
+                if t.payload.kind == "add" or t.payload.kind in KERNEL_KINDS}
+        roots = fold_roots(self._graph, cand) if cand else {}
+        for nid, root in roots.items():
+            cand[nid].root = root
+            cand[nid].out = cand[root].out
+        self._root_of.update(roots)
+
     def _c_keys(self, payload, a_leaf, b_leaf, upper) -> list:
         """Output occupancy via the one-shot boolean matmul of bsmm.
 
@@ -632,6 +686,9 @@ class PallasEngine(LeafEngine):
         return (self.kernel, t.out.n, t.out.bs,
                 np.dtype(t.out.dtype).name)
 
+    def is_unfilled(self, leaf) -> bool:
+        return id(leaf) in self._unfilled
+
     def has_pending_for(self, leaf_ids) -> bool:
         for t in self._pending:
             if id(t.out) in leaf_ids or id(t.a_leaf) in leaf_ids or \
@@ -642,16 +699,26 @@ class PallasEngine(LeafEngine):
     def ready_wave(self) -> dict:
         """Ready deferred kernel tasks, grouped by :meth:`batch_key`.
 
-        Read-only: nothing is executed or committed.  The cross-plan
-        coalescer merges groups with equal keys across engines before
-        dispatching; :meth:`flush` consumes the same grouping locally.
+        A folded product is ready whole or not at all: its multiplies
+        join a wave together, with its folded adds, which retire with the
+        wave's :meth:`commit_tasks`.  Nothing is executed or committed
+        here.  The cross-plan coalescer merges groups with equal keys
+        across engines before dispatching; :meth:`flush` consumes the
+        same grouping locally.
         """
+        self._fold_fresh()
+        blocked = {t.root for t in self._pending
+                   if t.root is not None and t.pairs is not None
+                   and not self._ready(t)}
         groups: dict[tuple, list[_Pending]] = {}
         for t in self._pending:
-            if t.payload.kind not in HOST_KINDS \
-                    and t.payload.kind not in SOLVE_KINDS \
-                    and self._ready(t):
-                groups.setdefault(self.batch_key(t), []).append(t)
+            if t.root is not None:
+                if t.root in blocked:
+                    continue
+            elif t.payload.kind in HOST_KINDS \
+                    or t.payload.kind in SOLVE_KINDS or not self._ready(t):
+                continue
+            groups.setdefault(self.batch_key(t), []).append(t)
         return groups
 
     def solve_wave(self) -> dict:
@@ -691,11 +758,12 @@ class PallasEngine(LeafEngine):
 
         Returns True if anything ran — the progress signal both
         :meth:`flush` and the coalescer's drain loop use.  Traced, a pass
-        that runs at least one fill is one ``engine.flush.host`` span.
+        that runs at least one fill is one ``engine.flush.host`` span;
+        folded adds never run here.
         """
+        self._fold_fresh()
         tr = self.tracer
-        if tr.enabled and any(t.payload.kind in HOST_KINDS and self._ready(t)
-                              for t in self._pending):
+        if tr.enabled and any(self._host_ready(t) for t in self._pending):
             with tr.span("engine.flush.host", track="engine") as sp:
                 return self._host_pass(sp)
         return self._host_pass(None)
@@ -705,7 +773,7 @@ class PallasEngine(LeafEngine):
         blocks = 0
         rest = []
         for t in self._pending:
-            if t.payload.kind in HOST_KINDS and self._ready(t):
+            if self._host_ready(t):
                 if t.payload.kind == "add":
                     self._run_add(t)
                 elif t.payload.kind == "scale":
@@ -722,6 +790,10 @@ class PallasEngine(LeafEngine):
             span.set(adds=done["add"], transposes=done["transpose"],
                      scales=done["scale"], blocks=blocks)
         return any(done.values())
+
+    def _host_ready(self, t: _Pending) -> bool:
+        return t.payload.kind in HOST_KINDS and t.root is None \
+            and self._ready(t)
 
     def commit_tasks(self, tasks: list, wave_record: Optional[dict] = None
                      ) -> None:
@@ -792,22 +864,21 @@ class PallasEngine(LeafEngine):
         a_leaf = av.leaf
         b_leaf = bv.leaf if bv is not None else None
         out: MatrixChunk = g.value_of(node.nid)
-        if payload.kind in HOST_KINDS or payload.kind in SOLVE_KINDS:
-            # host fills and solve waves assign (not scatter-add) every
-            # output block, so re-deferring without zeroing is exact
-            self._defer(_Pending(node.nid, payload, out.leaf, a_leaf,
-                                 b_leaf))
-        else:
+        # every wave, host fill and solve wave assigns (not scatter-adds)
+        # each output block it owns, so re-deferring without zeroing is
+        # exact: a wave's slots each get at least one pair
+        entry = _Pending(node.nid, payload, out.leaf, a_leaf, b_leaf)
+        if payload.kind in KERNEL_KINDS:
             if payload.tau > 0.0:
-                pairs, _ = node.replay      # frozen at first execution
+                entry.pairs, _ = node.replay    # frozen at first execution
             else:
                 probe = dataclasses.replace(payload, trunc=None)
-                pairs, _ = leaf_task_pairs(probe, a_leaf, b_leaf)
-            # zero first: waves only scatter-add into surviving out slots
-            for blk in out.leaf.blocks.values():
-                blk[...] = 0.0
-            self._defer(_Pending(node.nid, payload, out.leaf, a_leaf,
-                                 b_leaf, pairs))
+                entry.pairs, _ = leaf_task_pairs(probe, a_leaf, b_leaf)
+        root = self._root_of.get(node.nid)
+        if root is not None:                    # the fold decided at first
+            entry.root = root
+            entry.out = g.value_of(root).leaf
+        self._defer(entry)
         out.norm2 = None
         out.trace = None
 
@@ -815,7 +886,8 @@ class PallasEngine(LeafEngine):
         """Attributes of the just-committed wave for its engine.wave span."""
         w = self._waves[-1]
         return {k: w[k] for k in ("kernel", "bs", "tasks", "pairs",
-                                  "padded_pairs", "c_blocks", "bytes_packed")
+                                  "padded_pairs", "c_blocks", "fused_adds",
+                                  "bytes_packed")
                 if k in w}
 
     def _run_wave(self, groups: dict[tuple, list[_Pending]]) -> None:
@@ -952,13 +1024,21 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
     ``bsmm_pairs``; the ``gemm`` path gathers ``table[sa]`` and
     ``table[sb]`` from it.
 
+    Output slots are numbered once per distinct output leaf, so the
+    partials of a folded product (tasks sharing their root's leaf) share
+    one segment per output block and the kernel sums them; folded adds in
+    ``tasks`` carry no pairs and are only counted (``fused_adds``).
+
     Numerical identity with per-engine dispatch: output slots are numbered
-    task-by-task in structure order and pairs are sorted by a *stable*
+    leaf-by-leaf in structure order and pairs are sorted by a *stable*
     argsort on segment id, so every output block accumulates its products
-    in the same order regardless of which other tasks share the wave.
+    in the same order — task registration order, then pair order —
+    regardless of which other tasks share the wave.
     """
     from repro.kernels import ops as kops
 
+    fused = sum(t.pairs is None for t in tasks)
+    tasks = [t for t in tasks if t.pairs is not None]
     with tracer.span("engine.wave.pack", track="engine") as sp:
         table, sa, sb, seg, n_slots, shared = _pack_wave(tasks)
         n_pairs = len(seg)
@@ -1001,24 +1081,123 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
         "kernel": kernel, "bs": bs, "tasks": len(tasks),
         "pairs": int(n_pairs), "padded_pairs": int(padded),
         "unique_blocks": len(table), "shared_blocks": shared,
-        "c_blocks": int(n_slots), "dispatch_s": dispatch,
+        "c_blocks": int(n_slots), "fused_adds": fused,
+        "dispatch_s": dispatch,
         "bytes_packed": int(table.nbytes + c.nbytes),
         **ran_on(c_dev, use_pallas=True, interpret=interpret),
     }
     with tracer.span("engine.wave.unpack", track="engine",
                      c_blocks=int(n_slots)):
         base = 0
-        for t in tasks:
-            keys = list(t.out.blocks)
-            unpack_blocks(t.out, keys, c[base:base + len(keys)])
+        for leaf in _wave_outputs(tasks):
+            keys = list(leaf.blocks)
+            unpack_blocks(leaf, keys, c[base:base + len(keys)])
             base += len(keys)
     return record
+
+
+def _wave_outputs(tasks: list[_Pending]) -> list[LeafMatrix]:
+    """Distinct output leaves of a wave's kernel tasks, in first-use order.
+
+    The multiplies of a folded product share their root's leaf; it gets
+    its output slots once.
+    """
+    outs: dict[int, LeafMatrix] = {}
+    for t in tasks:
+        outs.setdefault(id(t.out), t.out)
+    return list(outs.values())
+
+
+def wave_counts(tasks: list[_Pending]) -> dict:
+    """``tasks``, ``pairs``, ``c_blocks`` and ``fused_adds`` of a wave's
+    share of tasks, counted as :func:`dispatch_packed_wave` counts them."""
+    kernel = [t for t in tasks if t.pairs is not None]
+    return {"tasks": len(kernel),
+            "pairs": sum(len(t.pairs) for t in kernel),
+            "c_blocks": sum(len(lf.blocks) for lf in _wave_outputs(kernel)),
+            "fused_adds": len(tasks) - len(kernel)}
+
+
+def fold_roots(g, cand: dict) -> dict:
+    """The adds the kernel can absorb, as ``{nid: root add nid}``.
+
+    ``cand`` maps nid to the pending multiply-kind and add tasks whose
+    fold is undecided.  An add is *fusable* when each of its inputs is
+    such a multiply-kind output or another fusable add's output, and is
+    read by nothing else: one fetch consumer in the graph's dependency
+    lists (this add), and no place in the tree of a top-level result —
+    a task registered with no parent, which a ``Matrix``, a ``Plan``
+    output or a raw caller may hold.  A *root* is a fusable add whose
+    output feeds no fusable add.  The result maps every fusable add and
+    every multiply-kind task under one to its root (a root to itself);
+    an add with any other input — a scale, a transpose, a data leaf, a
+    partial read twice — stays a host add.
+    """
+    lo = min(cand)
+    # Top-level tasks register one after another, each whole before the
+    # next starts, and a chunk names only nodes that existed when it was
+    # made.  So every node older than ``top``, the top-level task under
+    # which the oldest candidate was registered, holds no candidate, and
+    # the scan starts at ``top`` and prunes below it.
+    top = lo
+    while g.nodes[top].parent is not None:
+        top = g.nodes[top].parent
+    uses: dict[int, int] = {}       # fetch consumers of a candidate
+    user: dict[int, int] = {}       # ... and the last of them
+    held: set[int] = set()          # candidates a top-level result holds
+    seen: set[int] = set()
+    for node in g.nodes[top:]:
+        for d in node.deps:
+            if d.fetch and d.nid is not None:
+                r = g.resolve(d.nid)
+                if r in cand:
+                    uses[r] = uses.get(r, 0) + 1
+                    user[r] = node.nid
+        if node.parent is not None:
+            continue
+        stack = [node.nid]
+        while stack:
+            r = g.resolve(stack.pop())
+            if r is None or r < top or r in seen:
+                continue
+            seen.add(r)
+            if r in cand:
+                held.add(r)
+                continue
+            n = g.nodes[r]
+            if n.value is None:
+                # no chunk yet: a registration still open (a flush
+                # inside it) may come to name whatever its subtasks made
+                stack.extend(n.children)
+            elif not getattr(n.value, "is_leaf", True):
+                stack.extend(c for c in n.value.children if c is not None)
+
+    def alone(r: int) -> bool:
+        return r in cand and uses.get(r) == 1 and r not in held
+
+    fusable: set[int] = set()
+    for nid in sorted(cand):
+        p = cand[nid].payload
+        if p.kind == "add" and all(
+                alone(r) and (cand[r].payload.kind != "add" or r in fusable)
+                for r in (g.resolve(p.a), g.resolve(p.b))):
+            fusable.add(nid)
+    roots: dict[int, int] = {}
+    for nid in sorted(fusable, reverse=True):     # consumers first
+        up = user.get(nid)
+        roots[nid] = roots[up] if up in fusable else nid
+    for nid, t in cand.items():
+        if t.payload.kind != "add" and user.get(nid) in fusable \
+                and alone(nid):
+            roots[nid] = roots[user[nid]]
+    return roots
 
 
 def _pack_wave(tasks: list[_Pending]) -> tuple:
     """Host packing of one wave: ``(table, sa, sb, seg, n_slots, shared)``.
 
-    Output slots are numbered task-by-task in structure order.  Operands
+    Output slots are numbered leaf-by-leaf in structure order, once per
+    distinct output leaf (:func:`_wave_outputs`).  Operands
     are packed *uniquely* into one float32 ``table`` that both sides
     share — one slot per distinct (leaf, key, transpose) block, so a block
     that is an A operand of one pair and a B operand of another is packed
@@ -1046,11 +1225,15 @@ def _pack_wave(tasks: list[_Pending]) -> tuple:
     sa = np.empty((n_pairs,), np.int32)
     sb = np.empty((n_pairs,), np.int32)
     seg = np.empty((n_pairs,), np.int32)
-    p = 0
+    out_slots: dict[int, dict] = {}
     n_slots = 0
+    for leaf in _wave_outputs(tasks):
+        out_slots[id(leaf)] = {key: n_slots + i
+                               for i, key in enumerate(leaf.blocks)}
+        n_slots += len(leaf.blocks)
+    p = 0
     for t in tasks:
-        key_slot = {key: n_slots + i for i, key in enumerate(t.out.blocks)}
-        n_slots += len(t.out.blocks)
+        key_slot = out_slots[id(t.out)]
         srcs = {"a": t.a_leaf, "b": t.b_leaf}
         for src_a, ka, tra, src_b, kb, trb, out_key in t.pairs:
             sa[p] = slot_of(srcs[src_a], ka, tra)

@@ -302,12 +302,18 @@ def qt_to_dense(g: CTGraph, nid: Optional[int], params: QTParams
     one; upper-storage leaves expand to full symmetric leaves).
     """
     g.flush()   # deferred leaf waves must have filled block data
+    eng = g._engine
 
     def read(nid: Optional[int], n: int) -> np.ndarray:
         chunk: Optional[MatrixChunk] = g.value_of(nid)
         if chunk is None:
             return np.zeros((n, n))
         if chunk.is_leaf:
+            if eng is not None and eng.is_unfilled(chunk.leaf):
+                raise RuntimeError(
+                    f"node {g.resolve(nid)} is a partial product summed "
+                    "into its root's wave; its own numbers were never "
+                    "computed")
             return chunk.leaf.to_dense()  # full symmetric when upper storage
         out = np.zeros((n, n))
         h = n // 2

@@ -135,6 +135,9 @@ class MeshEngine(PallasEngine):
     """
 
     name = "mesh"
+    # a wave's tasks are split across chips by ownership, so a product's
+    # partials cannot share one segment: no fold of add trees
+    _FOLD_SUMS = False
 
     def __init__(self, n_dev: Optional[int] = None, kernel: str = "pairs",
                  interpret: Optional[bool] = None,

@@ -21,8 +21,9 @@ per plan.  :class:`WaveCoalescer` instead:
    consistent.
 
 Numerical identity with per-plan flushing is structural, not accidental:
-output slots are numbered task-by-task, pair order within a task is
-preserved, and the segment sort is stable — so every output block
+output slots are numbered leaf-by-leaf (a folded product's partials share
+their root's), pair order within a task is preserved, and the segment
+sort is stable — so every output block
 accumulates exactly the pair products it would have accumulated alone,
 in the same order, in float32 (see ``dispatch_packed_wave``).  Tests pin
 this bitwise.
@@ -35,7 +36,8 @@ from __future__ import annotations
 
 from typing import Optional
 
-from repro.core.engine import PallasEngine, dispatch_packed_wave
+from repro.core.engine import (PallasEngine, dispatch_packed_wave,
+                               wave_counts)
 from repro.obs.metrics import MetricSet
 from repro.obs.tracer import NOOP
 
@@ -126,12 +128,11 @@ class WaveCoalescer:
         # to (approximately) the merged wave
         total_pairs = max(record["pairs"], 1)
         for eng, tasks in parts:
-            pe_pairs = sum(len(t.pairs) for t in tasks)
-            share = pe_pairs / total_pairs
+            counts = wave_counts(tasks)
+            share = counts["pairs"] / total_pairs
             eng.commit_tasks(tasks, wave_record={
-                "kernel": kernel, "bs": bs, "tasks": len(tasks),
-                "pairs": int(pe_pairs), "padded_pairs": int(pe_pairs),
-                "c_blocks": sum(len(t.out.blocks) for t in tasks),
+                "kernel": kernel, "bs": bs, **counts,
+                "padded_pairs": counts["pairs"],
                 "dispatch_s": record["dispatch_s"] * share,
                 "bytes_packed": int(record["bytes_packed"] * share),
                 "batch_key": list(key), "coalesced": len(parts),

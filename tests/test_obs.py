@@ -122,15 +122,17 @@ class TestSessionSpans:
 
 
 def _replayed(product, trace=True):
-    """A compiled X @ X or X.sym_square() on the pallas engine, run once
-    and replayed once with tracing on only for the replay (the shape of
-    one benchmark op); returns the session and the replay's tracer."""
+    """A compiled X @ X + X or X.sym_square() + X on the pallas engine,
+    run once and replayed once with tracing on only for the replay (the
+    shape of one benchmark op); returns the session and the replay's
+    tracer.  The kernel sums the product's own partials; the ``+ X`` has
+    a data leaf for an input, so it cannot fold and stays a host fill."""
     a = _banded()
     a = (a + a.T) / 2
     sess = Session(engine="pallas", lazy=True, leaf_n=16, bs=8)
     X = sess.from_dense(a, upper=product == "sym_square", name="X")
-    plan = sess.compile(X.sym_square() if product == "sym_square"
-                        else X @ X)
+    plan = sess.compile((X.sym_square() if product == "sym_square"
+                         else X @ X) + X)
     plan.run()
     sess.flush()
     tr = Tracer() if trace else NOOP

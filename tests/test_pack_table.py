@@ -41,9 +41,13 @@ def _oracle_pack(tasks):
     seg = np.empty((n_pairs,), np.int32)
     p = 0
     n_slots = 0
+    out_slots = {}      # tasks of a folded product share their out leaf
     for t in tasks:
-        key_slot = {key: n_slots + i for i, key in enumerate(t.out.blocks)}
-        n_slots += len(t.out.blocks)
+        if id(t.out) not in out_slots:
+            out_slots[id(t.out)] = {key: n_slots + i
+                                    for i, key in enumerate(t.out.blocks)}
+            n_slots += len(t.out.blocks)
+        key_slot = out_slots[id(t.out)]
         srcs = {"a": t.a_leaf, "b": t.b_leaf}
         for src_a, ka, tra, src_b, kb, trb, out_key in t.pairs:
             sa[p] = slot_of(a_slots, a_list, srcs[src_a], ka, tra)
