@@ -5,8 +5,8 @@ structure) on randomly occupied block matrices, blocksizes 16/32/64, fill
 sweep.  CSV: bs,fill,gflops,block_multiplies,batches,useful_fraction.
 
 ``--compare-backends`` — run the same quadtree multiply once per leaf
-backend (numpy reference vs pallas batched waves, both kernel modes) and
-emit one JSON record with per-backend wall time and batched-pair counts:
+backend (numpy reference vs pallas batched waves) and emit one JSON
+record with per-backend wall time and batched-pair counts:
 
     PYTHONPATH=src python benchmarks/bench_leaf_multiply.py \
         --compare-backends [--n 256] [--pattern banded|random]
@@ -64,8 +64,7 @@ def compare_backends(n: int, pattern: str, leaf_n: int, bs: int,
     # engine instances bind to one graph, so each timed run gets a fresh one
     backends = {
         "numpy": lambda: "numpy",
-        "pallas-pairs": lambda: PallasEngine(kernel="pairs"),
-        "pallas-gemm": lambda: PallasEngine(kernel="gemm"),
+        "pallas": PallasEngine,
     }
     record = {
         "mode": "compare-backends", "n": n, "leaf_n": leaf_n, "bs": bs,
@@ -98,7 +97,6 @@ def compare_backends(n: int, pattern: str, leaf_n: int, bs: int,
         stats = sess.engine_stats()
         if stats:
             entry.update({
-                "kernel": stats.get("kernel"),
                 "waves": stats.get("waves"),
                 "batched_pairs": stats.get("batched_pairs"),
                 "padded_pairs": stats.get("padded_pairs"),

@@ -211,14 +211,15 @@ def wave_summary(sess, first: int = 0) -> tuple[dict, dict]:
     st = sess.engine_stats()
     waves = st["wave_log"][first:]
     backend = jax.default_backend()
-    gemm = [w for w in waves if w["kernel"] in ("pairs", "gemm")]
-    fields = {"tasks": sum(w["tasks"] for w in gemm), "waves": len(waves),
-              "pairs": sum(w["pairs"] for w in gemm)}
+    pair_waves = [w for w in waves if w["kernel"] == "pairs"]
+    fields = {"tasks": sum(w["tasks"] for w in pair_waves),
+              "waves": len(waves),
+              "pairs": sum(w["pairs"] for w in pair_waves)}
     checks = {
         "waves_on_" + backend: all(w["platform"] == backend for w in waves),
         "waves_pallas": all(w["use_pallas"] and
                             w["interpret"] == (backend == "cpu")
-                            for w in gemm),
+                            for w in pair_waves),
     }
     return fields, checks
 

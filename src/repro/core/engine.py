@@ -18,9 +18,9 @@ of that pluggable leaf engine:
   flush time the engine harvests *all* pending leaf tasks across the whole
   quadtree, packs every surviving block pair of every leaf into one
   ``(P, bs, bs)`` operand stream, and executes **one fused kernel call per
-  wave** — ``kernels.bsmm_pairs`` (gather-GEMM-scatter) or
-  ``kernels.batched_gemm`` + host scatter-add.  This lifts the paper's Fig 2
-  outer-product batching from per-leaf to per-graph: cross-leaf batching.
+  wave** — ``kernels.bsmm_pairs`` (gather-GEMM-scatter).  This lifts the
+  paper's Fig 2 outer-product batching from per-leaf to per-graph:
+  cross-leaf batching.
 
 Correctness of deferral rests on a structural fact both backends share: the
 *occupancy* of every leaf result is determined by the operand masks alone
@@ -40,7 +40,6 @@ padding counts, bytes packed) is recorded in :meth:`PallasEngine.stats`.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 import time
 from typing import Any, Optional
@@ -423,14 +422,12 @@ class PallasEngine(LeafEngine):
     leaves are float32 regardless of input dtype — expect ~1e-7 relative
     agreement with the float64 numpy backend, not bitwise equality.
 
-    kernel   : 'pairs' -> one fused kernels.bsmm_pairs gather-GEMM-scatter
-               call per wave; 'gemm' -> one kernels.batched_gemm call per
-               wave + host scatter-add (the cuBLAS-batched-gemm shape).
+    Each wave is one fused kernels.bsmm_pairs gather-GEMM-scatter call;
+    its records and spans name it ``"kernel": "pairs"``.
+
     interpret: None -> auto (compiled on TPU, interpret mode on CPU, any
                other backend raises; resolved by kernels.ops.resolve and
                recorded in every wave record with the result's platform).
-    block_t  : batch tile of the batched_gemm kernel, which zero-pads the
-               wave to a multiple of it internally.
     validate_structure : cross-check the pure-Python output structure of
                every leaf task against bsmm.compute_c_structure (the boolean
                matmul the TPU path uses).  Costs one eager JAX call per leaf
@@ -459,13 +456,9 @@ class PallasEngine(LeafEngine):
         g = self._graph
         return getattr(g, "tracer", NOOP) if g is not None else NOOP
 
-    def __init__(self, kernel: str = "pairs",
-                 interpret: Optional[bool] = None, block_t: int = 8,
+    def __init__(self, interpret: Optional[bool] = None,
                  validate_structure: bool = False):
-        assert kernel in ("pairs", "gemm")
-        self.kernel = kernel
         self.interpret = interpret
-        self.block_t = block_t
         self.validate_structure = validate_structure
         self._pending: list[_Pending] = []
         self._unfilled: set[int] = set()     # id() of placeholder out leaves
@@ -678,13 +671,12 @@ class PallasEngine(LeafEngine):
     def batch_key(self, t: _Pending) -> tuple:
         """Wave-compatibility key of a deferred kernel task.
 
-        Tasks agreeing on ``(kernel, leaf_n, bs, dtype)`` may share one
-        fused dispatch — within this engine's waves and, through the
-        serving layer's cross-plan coalescer (:mod:`repro.serve`),
-        across engines of different sessions.
+        Tasks agreeing on ``(leaf_n, bs, dtype)`` may share one fused
+        dispatch — within this engine's waves and, through the serving
+        layer's cross-plan coalescer (:mod:`repro.serve`), across engines
+        of different sessions.
         """
-        return (self.kernel, t.out.n, t.out.bs,
-                np.dtype(t.out.dtype).name)
+        return (t.out.n, t.out.bs, np.dtype(t.out.dtype).name)
 
     def is_unfilled(self, leaf) -> bool:
         return id(leaf) in self._unfilled
@@ -895,10 +887,10 @@ class PallasEngine(LeafEngine):
         for key, tasks in sorted(groups.items()):
             if tr.enabled:
                 with tr.span("engine.wave", track="engine") as sp:
-                    self._run_group(key[2], tasks)
+                    self._run_group(key[1], tasks)
                     sp.set(**self._wave_span_attrs())
             else:
-                self._run_group(key[2], tasks)
+                self._run_group(key[1], tasks)
             self._waves[-1].setdefault("batch_key", list(key))
             # commit this group immediately: a failure in a *later* group
             # must not leave these tasks pending, or a retrying flush would
@@ -908,14 +900,12 @@ class PallasEngine(LeafEngine):
     def _run_group(self, bs: int, tasks: list[_Pending]) -> None:
         """Pack every block pair of every leaf task into one kernel call."""
         self._waves.append(dispatch_packed_wave(
-            tasks, bs, kernel=self.kernel, block_t=self.block_t,
-            interpret=self.interpret, tracer=self.tracer))
+            tasks, bs, interpret=self.interpret, tracer=self.tracer))
 
     # -- reporting -----------------------------------------------------------
     def stats(self) -> dict:
         return {
             "backend": self.name,
-            "kernel": self.kernel,
             "waves": len(self._waves),
             "batched_pairs": sum(w["pairs"] for w in self._waves),
             "padded_pairs": sum(w["padded_pairs"] for w in self._waves),
@@ -970,10 +960,10 @@ def dispatch_solve_wave(tasks: list[_Pending], *, kind: str, n: int,
     }
 
 
-def _round_trip(tracer, host, call, fetch=np.asarray, **run_attrs):
-    """Upload ``host`` arrays, run ``call`` on them, fetch the result.
+def _round_trip(tracer, host, call, **run_attrs):
+    """Upload ``host`` arrays, run ``call`` on them, read the result back.
 
-    Returns ``(device result, fetch(device result))``, each step in its
+    Returns ``(device result, host copy)``, each step in its
     span: ``kernel.upload`` (``bytes`` sent), ``kernel.run`` (the jitted
     call, ``run_attrs``) and ``kernel.download`` (``bytes`` read back).
     Only a recording tracer waits for the device between the steps, so
@@ -993,7 +983,7 @@ def _round_trip(tracer, host, call, fetch=np.asarray, **run_attrs):
         if tracer.enabled:
             jax.block_until_ready(out)
     with tracer.span("kernel.download", track="engine") as sp:
-        res = fetch(out)
+        res = np.asarray(out)
         sp.set(bytes=int(out.nbytes))
     return out, res
 
@@ -1009,9 +999,8 @@ def ran_on(out, *, use_pallas: bool, interpret: bool) -> dict:
             "platform": platform}
 
 
-def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
-                         block_t: int, interpret: bool,
-                         tracer=NOOP) -> dict:
+def dispatch_packed_wave(tasks: list[_Pending], bs: int, *,
+                         interpret: bool, tracer=NOOP) -> dict:
     """Pack every block pair of every leaf task into one kernel call.
 
     Module-level so the cross-plan coalescer (:mod:`repro.serve.coalesce`)
@@ -1021,8 +1010,7 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
 
     Both sides of every pair read one operand table (``_pack_wave``): it
     is uploaded once and passed as both ``a_blocks`` and ``b_blocks`` of
-    ``bsmm_pairs``; the ``gemm`` path gathers ``table[sa]`` and
-    ``table[sb]`` from it.
+    ``bsmm_pairs``, which gathers each pair's operands by DMA.
 
     Output slots are numbered once per distinct output leaf, so the
     partials of a folded product (tasks sharing their root's leaf) share
@@ -1046,40 +1034,21 @@ def dispatch_packed_wave(tasks: list[_Pending], bs: int, *, kernel: str,
                shared_blocks=shared)
 
     _, interpret = kops.resolve(True, interpret)
-    run_attrs = {"pairs": int(n_pairs), "cap_c": int(n_slots)}
+
+    def pairs_call(blocks, *index):
+        return kops.bsmm_pairs(blocks, blocks, *index, cap_c=n_slots,
+                               use_pallas=True, interpret=interpret)
+
     t0 = time.perf_counter()
-    with tracer.span("kernel.dispatch", track="engine",
-                     kernel=kernel, bs=bs,
-                     pairs=int(n_pairs), c_blocks=int(n_slots)):
-        if kernel == "pairs":
-            def pairs_call(blocks, *index):
-                return kops.bsmm_pairs(blocks, blocks, *index,
-                                       cap_c=n_slots, use_pallas=True,
-                                       interpret=interpret)
-
-            c_dev, c = _round_trip(tracer, (table, sa, sb, seg),
-                                   pairs_call, **run_attrs)
-            padded = n_pairs
-        else:
-            # host gather feeds the cuBLAS-shaped batch (uploaded as it
-            # is gathered); batched_gemm zero-pads to a block_t multiple
-            # internally; the scatter-add joins the read-back
-            def scatter_add(prods_dev):
-                out = np.zeros((n_slots, bs, bs), np.float32)
-                np.add.at(out, seg, np.asarray(prods_dev))
-                return out
-
-            c_dev, c = _round_trip(
-                tracer, (table[idx] for idx in (sa, sb)),
-                functools.partial(kops.batched_gemm, block_t=block_t,
-                                  use_pallas=True, interpret=interpret),
-                fetch=scatter_add, **run_attrs)
-            padded = n_pairs + (-n_pairs) % block_t
+    with tracer.span("kernel.dispatch", track="engine", kernel="pairs",
+                     bs=bs, pairs=int(n_pairs), c_blocks=int(n_slots)):
+        c_dev, c = _round_trip(tracer, (table, sa, sb, seg), pairs_call,
+                               pairs=int(n_pairs), cap_c=int(n_slots))
     dispatch = time.perf_counter() - t0
 
     record = {
-        "kernel": kernel, "bs": bs, "tasks": len(tasks),
-        "pairs": int(n_pairs), "padded_pairs": int(padded),
+        "kernel": "pairs", "bs": bs, "tasks": len(tasks),
+        "pairs": int(n_pairs), "padded_pairs": int(n_pairs),
         "unique_blocks": len(table), "shared_blocks": shared,
         "c_blocks": int(n_slots), "fused_adds": fused,
         "dispatch_s": dispatch,
@@ -1193,33 +1162,31 @@ def fold_roots(g, cand: dict) -> dict:
     return roots
 
 
-def _pack_wave(tasks: list[_Pending]) -> tuple:
-    """Host packing of one wave: ``(table, sa, sb, seg, n_slots, shared)``.
+def number_wave(tasks: list[_Pending]) -> tuple:
+    """Slot numbering of one wave: ``(slots, sa, sb, seg, n_slots)``.
 
-    Output slots are numbered leaf-by-leaf in structure order, once per
-    distinct output leaf (:func:`_wave_outputs`).  Operands
-    are packed *uniquely* into one float32 ``table`` that both sides
-    share — one slot per distinct (leaf, key, transpose) block, so a block
-    that is an A operand of one pair and a B operand of another is packed
-    once — and pairs address it through ``sa``/``sb``, which is exactly
-    the slot-indexed gather the bsmm_pairs scalar-prefetch kernel is built
-    around.  Each slot is filled by a cast on copy from its leaf block
-    (the rounding of ``astype``, with no float64 staging); ``shared``
-    counts the slots both sides read.  Pairs come back in ascending
-    segment order (the bsmm_pairs accumulation contract), by a stable
-    sort.
+    ``slots`` lists one operand slot per distinct ``(leaf, key,
+    transpose)`` block, in first-use order (tasks in order, then pairs,
+    A side before B), so a block that is an A operand of one pair and a
+    B operand of another gets one slot.  Output slots are numbered
+    leaf-by-leaf in structure order, once per distinct output leaf
+    (:func:`_wave_outputs`).  ``sa``/``sb``/``seg`` give each pair's
+    operand and output slots, in ascending segment order (the bsmm_pairs
+    accumulation contract) by a stable sort, so each output block sums
+    its products in task order, then pair order.  One chip fills one
+    table from ``slots`` (:func:`_pack_wave`); the mesh executor derives
+    its per-device tables from the same numbering.
     """
     n_pairs = sum(len(t.pairs) for t in tasks)
-    slots: dict[tuple, int] = {}
-    blocks: list[np.ndarray] = []
+    index: dict[tuple, int] = {}
+    slots: list[tuple] = []
 
     def slot_of(leaf, key, tr):
         sk = (id(leaf), key, tr)
-        s = slots.get(sk)
+        s = index.get(sk)
         if s is None:
-            s = slots[sk] = len(blocks)
-            blk = leaf.blocks[key]
-            blocks.append(blk.T if tr else blk)
+            s = index[sk] = len(slots)
+            slots.append((leaf, key, tr))
         return s
 
     sa = np.empty((n_pairs,), np.int32)
@@ -1240,10 +1207,30 @@ def _pack_wave(tasks: list[_Pending]) -> tuple:
             sb[p] = slot_of(srcs[src_b], kb, trb)
             seg[p] = key_slot[out_key]
             p += 1
-    table = np.empty((len(blocks),) + blocks[0].shape, np.float32)
-    for s, blk in enumerate(blocks):
-        table[s] = blk
-    shared = len(np.intersect1d(sa, sb))
-
     order = np.argsort(seg, kind="stable")
-    return table, sa[order], sb[order], seg[order], n_slots, shared
+    return slots, sa[order], sb[order], seg[order], n_slots
+
+
+def fill_slots(out: np.ndarray, slots) -> None:
+    """Write each ``(leaf, key, transpose)`` slot's block into ``out[i]``:
+    a cast on copy into ``out``'s dtype (the rounding of ``astype``, with
+    no float64 staging)."""
+    for i, (leaf, key, tr) in enumerate(slots):
+        blk = leaf.blocks[key]
+        out[i] = blk.T if tr else blk
+
+
+def _pack_wave(tasks: list[_Pending]) -> tuple:
+    """Host packing of one wave: ``(table, sa, sb, seg, n_slots, shared)``.
+
+    The wave's :func:`number_wave` numbering, with its operand slots
+    filled into one float32 ``table`` that both sides of every pair
+    share (:func:`fill_slots`) — the slot-indexed gather the bsmm_pairs
+    scalar-prefetch kernel is built around.  ``shared`` counts the slots
+    both sides read.
+    """
+    slots, sa, sb, seg, n_slots = number_wave(tasks)
+    bs = tasks[0].out.bs
+    table = np.empty((len(slots), bs, bs), np.float32)
+    fill_slots(table, slots)
+    return table, sa, sb, seg, n_slots, len(np.intersect1d(sa, sb))

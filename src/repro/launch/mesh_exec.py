@@ -5,11 +5,16 @@ an executing backend: every deferred leaf-engine wave is partitioned over
 the devices of a 1-D jax mesh (``launch.mesh.make_spmm_mesh``), operand
 blocks move between devices through explicit, *counted* ring collectives
 (``jax.lax.ppermute``), and the per-device block GEMMs run as one
-``shard_map``-sharded :func:`repro.kernels.ops.batched_gemm` /
-:func:`~repro.kernels.ops.bsmm_pairs` dispatch per wave.  The per-device
-communication volume reported by :meth:`stats` is therefore *measured
-from the shipments actually performed*, not derived from the simulator's
-cost model.
+``shard_map``-sharded :func:`~repro.kernels.ops.bsmm_pairs` dispatch per
+wave.  The per-device communication volume reported by :meth:`stats` is
+therefore *measured from the shipments actually performed*, not derived
+from the simulator's cost model.
+
+One wave numbering: a wave's operand slots, output slots and pair order
+are the one-chip engine's (:func:`~repro.core.engine.number_wave`).
+:func:`plan_mesh_wave` derives the per-device pools, ship lists and pair
+tables from that numbering with array operations, on the host and
+without devices.
 
 Ownership (the paper's parent-worker rendering, §6/Table 1):
 
@@ -41,15 +46,10 @@ owning output and the result bitwise equal to the one-chip
 device-resident yet: wave staging and unpacking still round-trip through the
 host like the parent :class:`~repro.core.engine.PallasEngine` does. The
 sharding, collectives and per-device counters are real.
-
-The default kernel is ``"pairs"``, which gathers operand blocks by DMA.
-``"gemm"`` gathers both operands of every pair and their products into
-``(pairs, bs, bs)`` arrays, 192 KiB per pair at bs = 128: at the n = 65,536
-wave above the TPU compiler refuses it for lack of HBM
-(``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
 from typing import Optional
@@ -59,18 +59,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.engine import PallasEngine, _Pending, ran_on
+from repro.core.engine import (PallasEngine, _Pending, fill_slots,
+                               number_wave, ran_on)
 from repro.core.leaf import unpack_blocks
 from repro.kernels import ops as kops
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "mesh", "kernel", "shifts", "cap_c", "block_t", "use_pallas",
-    "interpret"))
-def mesh_wave(own_pool, sa, sb, seg, sels, *, mesh, kernel: str,
-              shifts: tuple, cap_c: int, block_t: int, use_pallas: bool,
-              interpret: bool):
-    """One device-sharded wave: ring-shift the halos, run the kernel.
+    "mesh", "shifts", "cap_c", "use_pallas", "interpret"))
+def mesh_wave(own_pool, sa, sb, seg, sels, *, mesh, shifts: tuple,
+              cap_c: int, use_pallas: bool, interpret: bool):
+    """One device-sharded wave: ring-shift the halos, run ``bsmm_pairs``.
 
     Every array is stacked over the mesh's ``"dev"`` axis: ``own_pool``
     ``(n_dev, cap_own, bs, bs)`` holds each device's home blocks;
@@ -79,32 +78,23 @@ def mesh_wave(own_pool, sa, sb, seg, sels, *, mesh, kernel: str,
     address the per-device pool (own segment, then one receive segment
     per shift).  Returns ``(n_dev, cap_c, bs, bs)`` output slots.
 
-    Module-level and jitted on its static signature (mesh, kernel,
-    shifts, caps) plus the argument shapes, so waves of one shape, such as
-    a plan replay, reuse one executable.
+    Module-level and jitted on its static signature (mesh, shifts,
+    caps) plus the argument shapes, so waves of one shape, such as a plan
+    replay, reuse one executable.
     """
     n_dev = mesh.shape["dev"]
 
     def body(own, sa_, sb_, seg_, *sels_):
         own = own[0]
-        sa1, sb1, seg1 = sa_[0], sb_[0], seg_[0]
         parts = [own]
         for shift, sel in zip(shifts, sels_):
             perm = [(r, (r + shift) % n_dev) for r in range(n_dev)]
             parts.append(jax.lax.ppermute(own[sel[0]], "dev", perm))
         pool = jnp.concatenate(parts, axis=0) if len(parts) > 1 \
             else parts[0]
-        if kernel == "pairs":
-            c = kops.bsmm_pairs(pool, pool, sa1, sb1, seg1, cap_c=cap_c,
-                                use_pallas=use_pallas, interpret=interpret)
-        else:
-            prods = kops.batched_gemm(pool[sa1], pool[sb1], block_t=block_t,
-                                      use_pallas=use_pallas,
-                                      interpret=interpret)
-            prods = jnp.where((seg1 < cap_c)[:, None, None], prods, 0)
-            c = jax.ops.segment_sum(
-                prods.astype(jnp.float32), jnp.minimum(seg1, cap_c),
-                num_segments=cap_c + 1)[:cap_c]
+        c = kops.bsmm_pairs(pool, pool, sa_[0], sb_[0], seg_[0],
+                            cap_c=cap_c, use_pallas=use_pallas,
+                            interpret=interpret)
         return c[None]
 
     spec = P("dev")
@@ -114,24 +104,151 @@ def mesh_wave(own_pool, sa, sb, seg, sels, *, mesh, kernel: str,
         own_pool, sa, sb, seg, *sels)
 
 
+@dataclasses.dataclass
+class MeshWavePlan:
+    """Host plan of one mesh wave (:func:`plan_mesh_wave`).
+
+    ``pool_slot[d, i]`` is the wave's operand slot at position ``i`` of
+    device ``d``'s pool (its own segment, ``cap_own`` long, then one
+    receive segment per ring shift), -1 for padding.  ``sa``/``sb``
+    address that pool and ``seg`` the device's local output slots;
+    device ``d``'s output slot ``j`` is the wave's slot ``base[d] + j``.
+    """
+    task_dev: np.ndarray    # (tasks,) device that runs each task
+    base: np.ndarray        # (n_dev,) first wave output slot of each device
+    n_out: np.ndarray       # (n_dev,) output slots of each device
+    home: np.ndarray        # (slots,) device that holds each operand slot
+    cap_own: int
+    pool_slot: np.ndarray   # (n_dev, pool_len)
+    shifts: tuple           # ring shifts that carry blocks, ascending
+    sels: tuple             # per shift (n_dev, cnt): own-pool rows sent
+    sa: np.ndarray          # (n_dev, cap_p) int32, padding 0
+    sb: np.ndarray          # (n_dev, cap_p) int32, padding 0
+    seg: np.ndarray         # (n_dev, cap_p) int32, padding cap_c
+    cap_c: int
+    shipped: int            # blocks the shifts carry, unpadded
+    pushed: np.ndarray      # (n_dev,) blocks uploaded host -> home
+    fetched: np.ndarray     # (n_dev,) received blocks not yet resident
+
+
+def plan_mesh_wave(slots, sa, sb, seg, out_counts, n_dev: int,
+                   owner: dict, resident: list) -> MeshWavePlan:
+    """Split one numbered wave over ``n_dev`` devices, on the host.
+
+    ``slots``/``sa``/``sb``/``seg`` are the wave's :func:`number_wave`
+    numbering and ``out_counts`` the output slots of each task, in task
+    order (one output leaf per task: the mesh does not fold).  Tasks go
+    to devices by the contiguous balanced split, so the wave's output
+    slots are the devices' local slots laid end to end, and the pairs —
+    sorted by ``seg`` — are one contiguous run per device, already in
+    the order each device's kernel sums them.
+
+    An operand slot lives on its leaf's owner (``owner``: leaf id ->
+    device; a leaf without one is homed on the first device that reads
+    it, and recorded).  ``resident[d]`` maps a slot key ``(id(leaf),
+    key, transpose)`` to the leaf version device ``d`` holds: a home slot
+    at another version counts as pushed, a received one as fetched, and
+    both maps are brought up to date.
+    """
+    nt = len(out_counts)
+    task_dev = ((np.arange(nt, dtype=np.int64) + 1) * n_dev - 1) // nt
+    n_out = np.bincount(task_dev, weights=out_counts,
+                        minlength=n_dev).astype(np.int64)
+    base = np.cumsum(n_out) - n_out
+    bounds = np.searchsorted(seg, np.append(base, n_out.sum()))
+    pair_dev = np.repeat(np.arange(n_dev), np.diff(bounds))
+
+    # slots are numbered in first-use order and tasks run in device
+    # order, so the first reader of a slot is its lowest reading device
+    first = np.full(len(slots), n_dev, np.int64)
+    np.minimum.at(first, sa, pair_dev)
+    np.minimum.at(first, sb, pair_dev)
+    keys = [(id(leaf), key, tr) for leaf, key, tr in slots]
+    vers = [getattr(leaf, "_version", 0) for leaf, _, _ in slots]
+    home = np.array([owner.setdefault(id(leaf), f) for (leaf, _, _), f
+                     in zip(slots, first.tolist())], np.int64)
+
+    def stale(d, s):
+        if resident[d].get(keys[s]) == vers[s]:
+            return False
+        resident[d][keys[s]] = vers[s]
+        return True
+
+    pushed = np.zeros(n_dev, np.int64)
+    for s, h in enumerate(home.tolist()):
+        pushed[h] += stale(h, s)
+    own = [np.flatnonzero(home == d) for d in range(n_dev)]
+    own_pos = np.empty(len(slots), np.int64)
+    for rows in own:
+        own_pos[rows] = np.arange(len(rows))
+
+    # the remote slots each device reads, grouped by ring shift
+    # (dst - home) mod n_dev; a (shift, src) pair has one destination
+    fetched = np.zeros(n_dev, np.int64)
+    ship: dict[int, list] = {}
+    for d in range(n_dev):
+        lo, hi = bounds[d], bounds[d + 1]
+        need = np.unique(np.concatenate([sa[lo:hi], sb[lo:hi]]))
+        far = need[home[need] != d]
+        fetched[d] = sum(stale(d, s) for s in far.tolist())
+        shift = (d - home[far]) % n_dev
+        for k in np.unique(shift).tolist():
+            lists = ship.setdefault(k, [far[:0]] * n_dev)
+            lists[(d - k) % n_dev] = far[shift == k]
+    shifts = tuple(sorted(ship))
+    cnts = [max(len(x) for x in ship[k]) for k in shifts]
+
+    cap_own = max(1, max(len(rows) for rows in own))
+    pool_slot = np.full((n_dev, cap_own + sum(cnts)), -1, np.int64)
+    for d, rows in enumerate(own):
+        pool_slot[d, :len(rows)] = rows
+    sels = []
+    off = cap_own
+    for k, cnt in zip(shifts, cnts):
+        sel = np.zeros((n_dev, cnt), np.int32)
+        for src, rows in enumerate(ship[k]):
+            sel[src, :len(rows)] = own_pos[rows]
+            pool_slot[(src + k) % n_dev, off:off + len(rows)] = rows
+        sels.append(sel)
+        off += cnt
+
+    cap_p = max(1, int(np.diff(bounds).max()))
+    cap_c = max(1, int(n_out.max()))
+    dsa = np.zeros((n_dev, cap_p), np.int32)
+    dsb = np.zeros((n_dev, cap_p), np.int32)
+    dseg = np.full((n_dev, cap_p), cap_c, np.int32)
+    pos = np.empty(len(slots), np.int64)
+    for d in range(n_dev):
+        at = np.flatnonzero(pool_slot[d] >= 0)
+        pos[pool_slot[d, at]] = at
+        lo, hi = bounds[d], bounds[d + 1]
+        dsa[d, :hi - lo] = pos[sa[lo:hi]]
+        dsb[d, :hi - lo] = pos[sb[lo:hi]]
+        dseg[d, :hi - lo] = seg[lo:hi] - base[d]
+    return MeshWavePlan(
+        task_dev=task_dev, base=base, n_out=n_out, home=home,
+        cap_own=cap_own, pool_slot=pool_slot, shifts=shifts,
+        sels=tuple(sels), sa=dsa, sb=dsb, seg=dseg, cap_c=cap_c,
+        shipped=int((pool_slot[:, cap_own:] >= 0).sum()),
+        pushed=pushed, fetched=fetched)
+
+
 class MeshEngine(PallasEngine):
     """Device-sharded leaf backend: ``Session(engine="mesh")``.
 
     Parameters
     ----------
     n_dev : devices to shard over (default: all visible jax devices).
-    kernel : ``"pairs"`` (the fused bsmm_pairs gather-GEMM-scatter, the
-        default) or ``"gemm"`` (batched_gemm + segment_sum scatter, which
-        materialises the gathered operands; small waves only).
     use_pallas / interpret : forwarded to :mod:`repro.kernels.ops`;
         ``None`` auto-selects (compiled Pallas on TPU, the XLA
         reference on CPU; other backends raise).
-    block_t : batch tile of the batched_gemm kernel.
 
     Inherits the deferral machinery, NIL/structure semantics, host-side
-    add/transpose/scale fills and the float32 precision contract of
-    :class:`~repro.core.engine.PallasEngine`; only wave *execution* (and
-    the communication bookkeeping that comes with it) is replaced.
+    add/transpose/scale fills, the wave numbering and the float32
+    precision contract of :class:`~repro.core.engine.PallasEngine`; only
+    wave *execution* (and the communication bookkeeping that comes with
+    it) is replaced: each device runs ``bsmm_pairs`` on its share of the
+    wave (:func:`mesh_wave`), planned by :func:`plan_mesh_wave`.
     """
 
     name = "mesh"
@@ -139,11 +256,10 @@ class MeshEngine(PallasEngine):
     # partials cannot share one segment: no fold of add trees
     _FOLD_SUMS = False
 
-    def __init__(self, n_dev: Optional[int] = None, kernel: str = "pairs",
+    def __init__(self, n_dev: Optional[int] = None,
                  interpret: Optional[bool] = None,
-                 use_pallas: Optional[bool] = None, block_t: int = 8):
-        super().__init__(kernel=kernel, interpret=interpret,
-                         block_t=block_t)
+                 use_pallas: Optional[bool] = None):
+        super().__init__(interpret=interpret)
         self.use_pallas = use_pallas
         self._n_dev_req = n_dev
         self._mesh = None
@@ -166,8 +282,6 @@ class MeshEngine(PallasEngine):
     # -- mesh ----------------------------------------------------------------
     def _ensure_mesh(self):
         if self._mesh is None:
-            import jax
-
             from .mesh import make_spmm_mesh
 
             avail = jax.device_count()
@@ -195,185 +309,80 @@ class MeshEngine(PallasEngine):
         n_dev = self.n_dev
         bsz = bs * bs * 4               # float32 wire format
         t0 = time.perf_counter()
-        # per-device counter snapshot: the wave's comm_log entry (and its
-        # engine.wave span) carries this wave's deltas, not the running sums
-        fetched0 = self._fetched_bytes.copy()
-        fblocks0 = self._fetched_blocks.copy()
-        pushed0 = self._pushed_bytes.copy()
-        coll0 = self._collective_bytes.copy()
-
-        # 1. task ownership: contiguous balanced split in registration
-        # (quadtree DFS ~ Morton) order — core.distributed's closed form
-        nt = len(tasks)
-        owners = ((np.arange(nt, dtype=np.int64) + 1) * n_dev - 1) // nt
-        owners = owners.astype(np.int32)
-
-        # 2. operand slots: one per distinct (leaf, key, transpose),
-        # homed on the leaf's owning device (producer, else first touch)
-        slot_home: dict[tuple, int] = {}
-        slot_val: dict[tuple, np.ndarray] = {}
-        slot_ver: dict[tuple, int] = {}
-        needs: list[dict] = [dict() for _ in range(n_dev)]  # ordered sets
-        for t, dev in zip(tasks, owners):
-            dev = int(dev)
+        slots, sa, sb, seg, _ = number_wave(tasks)
+        counts = [len(t.out.blocks) for t in tasks]
+        plan = plan_mesh_wave(slots, sa, sb, seg, counts, n_dev,
+                              self._owner, self._resident)
+        task_dev = plan.task_dev.tolist()
+        for t, dev in zip(tasks, task_dev):
             self._owner[id(t.out)] = dev
-            srcs = {"a": t.a_leaf, "b": t.b_leaf}
-            for src_a, ka, tra, src_b, kb, trb, _ in t.pairs:
-                for src, kk, tr in ((src_a, ka, tra), (src_b, kb, trb)):
-                    leaf = srcs[src]
-                    sk = (id(leaf), kk, tr)
-                    if sk not in slot_home:
-                        home = self._owner.setdefault(id(leaf), dev)
-                        slot_home[sk] = home
-                        blk = leaf.blocks[kk]
-                        slot_val[sk] = np.asarray(
-                            blk.T if tr else blk, np.float32)
-                        slot_ver[sk] = getattr(leaf, "_version", 0)
-                    needs[dev].setdefault(sk)
-
-        # 3. per-device own pools (+ push accounting: host -> home device
-        # uploads of blocks not resident at their current version)
-        own_keys: list[list] = [[] for _ in range(n_dev)]
-        own_pos: dict[tuple, int] = {}
-        for sk, h in slot_home.items():
-            own_pos[sk] = len(own_keys[h])
-            own_keys[h].append(sk)
-            if self._resident[h].get(sk) != slot_ver[sk]:
-                self._resident[h][sk] = slot_ver[sk]
-                self._pushed_bytes[h] += bsz
-        cap_own = max(1, max((len(k) for k in own_keys), default=1))
-        own_pool = np.zeros((n_dev, cap_own, bs, bs), np.float32)
+        own_pool = np.zeros((n_dev, plan.cap_own, bs, bs), np.float32)
         for d in range(n_dev):
-            for i, sk in enumerate(own_keys[d]):
-                own_pool[d, i] = slot_val[sk]
+            rows = plan.pool_slot[d, :plan.cap_own]
+            fill_slots(own_pool[d], [slots[s] for s in rows[rows >= 0]])
+        cnts = [sel.shape[1] for sel in plan.sels]
+        fetched = plan.fetched * bsz
+        pushed = plan.pushed * bsz
+        collective = np.full(n_dev, sum(cnts) * bsz, np.int64)
+        self._fetched_bytes += fetched
+        self._fetched_blocks += plan.fetched
+        self._pushed_bytes += pushed
+        self._collective_bytes += collective   # every device receives
 
-        # 4. shipments grouped by ring shift s = (dst - home) mod n_dev;
-        # SPMD tables: per shift every device sends the same padded count
-        ship: dict[int, list[list]] = {}    # shift -> per-src slot keys
-        fetched_now = 0
-        for d in range(n_dev):
-            for sk in needs[d]:
-                h = slot_home[sk]
-                if h == d:
-                    continue
-                s = (d - h) % n_dev
-                ship.setdefault(s, [[] for _ in range(n_dev)])[h].append(sk)
-                if self._resident[d].get(sk) != slot_ver[sk]:
-                    self._resident[d][sk] = slot_ver[sk]
-                    self._fetched_bytes[d] += bsz
-                    self._fetched_blocks[d] += 1
-                    fetched_now += 1
-        shifts = sorted(ship)
-        cnts = [max(len(lst) for lst in ship[s]) for s in shifts]
-        # pool position of slot sk as seen by device d: the own segment,
-        # then one recv segment per shift at a static offset
-        recv_pos: dict[tuple, int] = {}     # (shift, slot) -> pool pos
-        sels = []
-        off = cap_own
-        for s, cnt in zip(shifts, cnts):
-            sel = np.zeros((n_dev, cnt), np.int32)
-            for src in range(n_dev):
-                for i, sk in enumerate(ship[s][src]):
-                    sel[src, i] = own_pos[sk]
-                    recv_pos[(s, sk)] = off + i
-            sels.append(sel)
-            off += cnt
-        pool_len = off
-
-        def pos_on(d: int, sk: tuple) -> int:
-            h = slot_home[sk]
-            if h == d:
-                return own_pos[sk]
-            return recv_pos[((d - h) % n_dev, sk)]
-
-        # 5. per-device pair tables (sa/sb into the halo'd pool, seg into
-        # the device-local output slots; cap-padded, seg=cap_c invalid)
-        out_base: list[int] = []
-        n_out = [0] * n_dev
-        for t, dev in zip(tasks, owners):
-            out_base.append(n_out[int(dev)])
-            n_out[int(dev)] += len(t.out.blocks)
-        cap_c = max(1, max(n_out))
-        dev_pairs: list[list] = [[] for _ in range(n_dev)]
-        n_pairs = 0
-        for t, dev, base in zip(tasks, owners, out_base):
-            dev = int(dev)
-            key_slot = {key: base + i
-                        for i, key in enumerate(t.out.blocks)}
-            srcs = {"a": t.a_leaf, "b": t.b_leaf}
-            for src_a, ka, tra, src_b, kb, trb, out_key in t.pairs:
-                dev_pairs[dev].append(
-                    (pos_on(dev, (id(srcs[src_a]), ka, tra)),
-                     pos_on(dev, (id(srcs[src_b]), kb, trb)),
-                     key_slot[out_key]))
-                n_pairs += 1
-        cap_p = max(1, max(len(p) for p in dev_pairs))
-        sa = np.zeros((n_dev, cap_p), np.int32)
-        sb = np.zeros((n_dev, cap_p), np.int32)
-        seg = np.full((n_dev, cap_p), cap_c, np.int32)
-        for d in range(n_dev):
-            # ascending output slots (bsmm_pairs accumulation contract;
-            # the cap_c padding sorts to the tail)
-            for i, (pa, pb, pc) in enumerate(
-                    sorted(dev_pairs[d], key=lambda x: x[2])):
-                sa[d, i], sb[d, i], seg[d, i] = pa, pb, pc
-
-        # 6. the sharded dispatch: ring-shift the halos, run the kernel
+        # the sharded dispatch: ring-shift the halos, run the kernel
         use_pallas, interpret = kops.resolve(self.use_pallas, self.interpret)
         tr = self.tracer
-        if tr.enabled and shifts:
+        if tr.enabled and plan.shifts:
             tr.instant("collective.ppermute", track="engine",
-                       shifts=len(shifts),
-                       shipped_blocks=int(sum(len(lst) for s in shifts
-                                              for lst in ship[s])),
+                       shifts=len(plan.shifts),
+                       shipped_blocks=plan.shipped,
                        padded_shipped_blocks=int(sum(cnts) * n_dev))
         t_dispatch = time.perf_counter()
-        with tr.span("kernel.dispatch", track="engine", kernel=self.kernel,
-                     bs=bs, n_dev=n_dev, pairs=int(n_pairs)):
-            c_dev = mesh_wave(own_pool, sa, sb, seg, tuple(sels), mesh=mesh,
-                              kernel=self.kernel, shifts=tuple(shifts),
-                              cap_c=cap_c, block_t=self.block_t,
-                              use_pallas=use_pallas, interpret=interpret)
+        with tr.span("kernel.dispatch", track="engine", kernel="pairs",
+                     bs=bs, n_dev=n_dev, pairs=len(seg)):
+            c_dev = mesh_wave(own_pool, plan.sa, plan.sb, plan.seg,
+                              plan.sels, mesh=mesh, shifts=plan.shifts,
+                              cap_c=plan.cap_c, use_pallas=use_pallas,
+                              interpret=interpret)
             c_np = np.asarray(c_dev)
         dispatch = time.perf_counter() - t_dispatch
 
-        # 7. scatter into the placeholder out leaves; produced blocks are
+        # scatter into the placeholder out leaves; produced blocks are
         # now resident on their owner (backed by the retained shard ref)
-        for t, dev, base in zip(tasks, owners, out_base):
-            dev = int(dev)
+        start = 0
+        for t, dev, n in zip(tasks, task_dev, counts):
             keys = list(t.out.blocks)
-            unpack_blocks(t.out, keys, c_np[dev, base:base + len(keys)])
+            lo = start - plan.base[dev]
+            unpack_blocks(t.out, keys, c_np[dev, lo:lo + n])
+            start += n
             self._dev_out[id(t.out)] = c_dev
             ver = getattr(t.out, "_version", 0)
             for key in keys:
                 self._resident[dev][(id(t.out), key, False)] = ver
 
         wall = time.perf_counter() - t0
-        shipped = sum(len(lst) for s in shifts for lst in ship[s])
-        padded_ship = sum(cnts) * n_dev
-        self._collective_bytes += sum(cnts) * bsz   # every device receives
         self._waves.append({
-            "kernel": self.kernel, "bs": bs, "tasks": nt,
-            "pairs": int(n_pairs), "padded_pairs": int(cap_p * n_dev),
-            "unique_blocks": len(slot_home), "c_blocks": int(sum(n_out)),
+            "kernel": "pairs", "bs": bs, "tasks": len(tasks),
+            "pairs": len(seg), "padded_pairs": int(plan.sa.size),
+            "unique_blocks": len(slots), "c_blocks": int(plan.n_out.sum()),
             "dispatch_s": dispatch,
             "bytes_packed": int(own_pool.nbytes + c_np.nbytes),
             **ran_on(c_dev, use_pallas=use_pallas, interpret=interpret),
         })
         self._comm_log.append({
-            "bs": bs, "n_dev": n_dev, "tasks": nt, "pairs": int(n_pairs),
-            "shifts": len(shifts), "shipped_blocks": int(shipped),
-            "padded_shipped_blocks": int(padded_ship),
-            "fetched_blocks": int(fetched_now),
-            "pool_len": int(pool_len), "cap_c": int(cap_c),
-            "c_blocks_by_dev": list(n_out),
+            "bs": bs, "n_dev": n_dev, "tasks": len(tasks), "pairs": len(seg),
+            "shifts": len(plan.shifts), "shipped_blocks": plan.shipped,
+            "padded_shipped_blocks": int(sum(cnts) * n_dev),
+            "fetched_blocks": int(plan.fetched.sum()),
+            "pool_len": int(plan.pool_slot.shape[1]),
+            "cap_c": plan.cap_c, "c_blocks_by_dev": plan.n_out.tolist(),
             "wall_s": wall,
             # this wave's measured per-device counter deltas (exported as
             # Perfetto counter tracks; see obs/export.mesh_stats_events)
-            "fetched_bytes_by_dev": (self._fetched_bytes - fetched0).tolist(),
-            "fetched_blocks_by_dev": (self._fetched_blocks - fblocks0).tolist(),
-            "pushed_bytes_by_dev": (self._pushed_bytes - pushed0).tolist(),
-            "collective_bytes_by_dev": (self._collective_bytes - coll0).tolist(),
+            "fetched_bytes_by_dev": fetched.tolist(),
+            "fetched_blocks_by_dev": plan.fetched.tolist(),
+            "pushed_bytes_by_dev": pushed.tolist(),
+            "collective_bytes_by_dev": collective.tolist(),
         })
 
     def _wave_span_attrs(self) -> dict:

@@ -1,16 +1,16 @@
 """Cross-plan wave coalescer: one fused kernel call for many plans.
 
 The deferred engines already batch *within* a graph: every pending leaf
-task whose operands are final joins one fused ``bsmm_pairs`` /
-``batched_gemm`` dispatch at flush.  A serving front end runs several
+task whose operands are final joins one fused ``bsmm_pairs`` dispatch at
+flush.  A serving front end runs several
 plans per batch — possibly in *different* sessions, each with its own
 engine — and flushing them one by one would dispatch one undersized wave
 per plan.  :class:`WaveCoalescer` instead:
 
 1. asks every engine for its ready kernel tasks grouped by
    :meth:`~repro.core.engine.PallasEngine.batch_key`
-   (``(kernel, leaf_n, bs, dtype)``),
-2. merges groups with equal keys across engines,
+   (``(leaf_n, bs, dtype)``),
+2. merges groups with equal keys and interpret modes across engines,
 3. packs each merged group through the same
    :func:`~repro.core.engine.dispatch_packed_wave` the engines use
    themselves — one kernel call per key per round, whose operands of
@@ -33,8 +33,6 @@ the mesh executor (device-resident buffers, counted collectives) and the
 immediate numpy backend flush through their own paths.
 """
 from __future__ import annotations
-
-from typing import Optional
 
 from repro.core.engine import (PallasEngine, dispatch_packed_wave,
                                wave_counts)
@@ -87,13 +85,13 @@ class WaveCoalescer:
             merged: dict = {}
             for _, eng in mergeable:
                 for key, tasks in eng.ready_wave().items():
-                    # kernel params beyond the batch key must also agree
-                    # for the shares to be dispatch-compatible
-                    mk = (key, eng.block_t, eng.interpret)
+                    # the interpret mode must also agree for the shares
+                    # to be dispatch-compatible
+                    mk = (key, eng.interpret)
                     merged.setdefault(mk, []).append((eng, tasks))
-            for (key, block_t, interpret), parts in sorted(
+            for (key, interpret), parts in sorted(
                     merged.items(), key=lambda kv: kv[0][0]):
-                self._dispatch(key, block_t, interpret, parts)
+                self._dispatch(key, interpret, parts)
                 dispatches += 1
                 progressed = True
             if not any(eng._pending for _, eng in mergeable):
@@ -104,16 +102,14 @@ class WaveCoalescer:
                     "dependencies across in-flight plans")
         return dispatches
 
-    def _dispatch(self, key: tuple, block_t: int, interpret: bool,
-                  parts: list) -> None:
-        kernel, _, bs, _ = key
+    def _dispatch(self, key: tuple, interpret: bool, parts: list) -> None:
+        _, bs, _ = key
         all_tasks = [t for _, tasks in parts for t in tasks]
         with self.tracer.span("serve.wave", track="serve",
                               engines=len(parts), tasks=len(all_tasks),
-                              kernel=kernel, bs=bs):
-            record = dispatch_packed_wave(
-                all_tasks, bs, kernel=kernel, block_t=block_t,
-                interpret=interpret, tracer=self.tracer)
+                              kernel="pairs", bs=bs):
+            record = dispatch_packed_wave(all_tasks, bs, interpret=interpret,
+                                          tracer=self.tracer)
         record["batch_key"] = list(key)
         record["engines"] = len(parts)
         self.waves.append(record)
@@ -131,7 +127,7 @@ class WaveCoalescer:
             counts = wave_counts(tasks)
             share = counts["pairs"] / total_pairs
             eng.commit_tasks(tasks, wave_record={
-                "kernel": kernel, "bs": bs, **counts,
+                "kernel": "pairs", "bs": bs, **counts,
                 "padded_pairs": counts["pairs"],
                 "dispatch_s": record["dispatch_s"] * share,
                 "bytes_packed": int(record["bytes_packed"] * share),

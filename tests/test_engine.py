@@ -39,15 +39,7 @@ PATTERNS = {
     "banded": lambda: banded_mask(64, 6),
     "s2": _s2_mask,
 }
-ENGINES = ["pallas-pairs", "pallas-gemm"]
-
-
-def _engine(spec):
-    if spec == "pallas-pairs":
-        return PallasEngine(kernel="pairs")
-    if spec == "pallas-gemm":
-        return PallasEngine(kernel="gemm")
-    return make_engine(spec)
+ENGINES = ["pallas"]
 
 
 def _both(build, check):
@@ -55,7 +47,7 @@ def _both(build, check):
     outs = {}
     graphs = {}
     for spec in ["numpy"] + ENGINES:
-        g = CTGraph(engine=_engine(spec))
+        g = CTGraph(engine=spec)
         rc = build(g)
         outs[spec] = qt_to_dense(g, rc, PARAMS)
         graphs[spec] = g
@@ -127,7 +119,7 @@ class TestMultiplyEquivalence:
         a = np.zeros((64, 64)); a[:16, 48:] = 1.0
         b = np.zeros((64, 64)); b[:16, :16] = 1.0
         for spec in ["numpy"] + ENGINES:
-            g = CTGraph(engine=_engine(spec))
+            g = CTGraph(engine=spec)
             ra = qt_from_dense(g, a, PARAMS)
             rb = qt_from_dense(g, b, PARAMS)
             rc = qt_multiply(g, PARAMS, ra, rb)
@@ -220,8 +212,8 @@ class TestGraphInvariance:
         float32 (half the bytes of numpy's float64 leaves)."""
         a = values_for_mask(banded_mask(64, 5), seed=22)
         results = {}
-        for spec in ["numpy", "pallas-pairs"]:
-            g = CTGraph(engine=_engine(spec))
+        for spec in ["numpy", "pallas"]:
+            g = CTGraph(engine=spec)
             ra = qt_from_dense(g, a, PARAMS)
             rb = qt_from_dense(g, a, PARAMS)
             sim = ClusterSim(4, seed=0)
@@ -229,7 +221,7 @@ class TestGraphInvariance:
             sim.reset_stats()
             qt_multiply(g, PARAMS, ra, rb)
             results[spec] = sim.run(g)
-        ref, got = results["numpy"], results["pallas-pairs"]
+        ref, got = results["numpy"], results["pallas"]
         assert sum(got.tasks_per_worker) == sum(ref.tasks_per_worker)
         assert got.makespan == pytest.approx(ref.makespan, rel=0.02)
 
@@ -240,7 +232,7 @@ class TestEngineUnit:
         assert isinstance(make_engine(None), NumpyEngine)
         assert isinstance(make_engine("numpy"), NumpyEngine)
         assert isinstance(make_engine("pallas"), PallasEngine)
-        e = PallasEngine(kernel="gemm")
+        e = PallasEngine()
         assert make_engine(e) is e
         with pytest.raises(ValueError):
             make_engine("cuda")
@@ -279,15 +271,15 @@ class TestEngineUnit:
         qt_sym_square(g, PARAMS, rs)
         g.flush()   # would have asserted on any structure mismatch
 
-    @pytest.mark.parametrize("spec", ["numpy"] + ENGINES)
+    @pytest.mark.parametrize("spec", ["numpy"] + ENGINES + ["mesh"])
     def test_upper_operand_to_plain_multiply_rejected(self, spec):
-        """Both backends refuse a plain multiply on upper-storage leaves
+        """Every backend refuses a plain multiply on upper-storage leaves
         (the host-library contract) instead of silently dropping the
         mirrored lower triangle."""
         s = values_for_mask(random_symmetric_mask(64, 0.2, seed=35),
                             seed=35, symmetric=True)
         b = values_for_mask(banded_mask(64, 4), seed=36)
-        g = CTGraph(engine=_engine(spec))
+        g = CTGraph(engine=spec)
         rs = qt_from_dense(g, s, PARAMS, upper=True)
         rb = qt_from_dense(g, b, PARAMS)
         with pytest.raises(AssertionError):
@@ -320,18 +312,16 @@ class TestEngineUnit:
 
 @pytest.mark.pallas
 @settings(max_examples=10, deadline=None)
-@given(seed=st.integers(0, 1000), fill=st.floats(0.05, 0.4),
-       kernel=st.sampled_from(["pairs", "gemm"]))
-def test_property_engine_equivalence(seed, fill, kernel):
+@given(seed=st.integers(0, 1000), fill=st.floats(0.05, 0.4))
+def test_property_engine_equivalence(seed, fill):
     a = values_for_mask(random_mask(64, fill, seed=seed), seed=seed)
     b = values_for_mask(random_mask(64, fill, seed=seed + 1), seed=seed + 1)
     outs = {}
-    for eng in ("numpy", kernel):
-        spec = "numpy" if eng == "numpy" else PallasEngine(kernel=kernel)
-        g = CTGraph(engine=spec)
+    for eng in ("numpy", "pallas"):
+        g = CTGraph(engine=eng)
         ra = qt_from_dense(g, a, PARAMS)
         rb = qt_from_dense(g, b, PARAMS)
         rc = qt_multiply(g, PARAMS, ra, rb)
         outs[eng] = qt_to_dense(g, rc, PARAMS)
-    np.testing.assert_allclose(outs[kernel], outs["numpy"], **TOL)
+    np.testing.assert_allclose(outs["pallas"], outs["numpy"], **TOL)
     np.testing.assert_allclose(outs["numpy"], a @ b, atol=1e-10)

@@ -15,6 +15,10 @@ Pins the mesh leaf backend three ways:
    buffers and ownership/residency bookkeeping (free-then-reuse), and
    plan rebinds bump block versions so stale device copies are
    re-pushed, never silently reused.
+4. **Wave plan** — ``plan_mesh_wave`` splits the one-chip numbering of a
+   wave over 1-4 devices, on the host and with no devices: each device
+   runs exactly the one-chip rows of its tasks, holds every block it
+   reads once, and counts a fetch per remote block it reads.
 """
 import os
 import pathlib
@@ -26,7 +30,9 @@ import numpy as np
 import pytest
 
 from repro import Session
-from repro.core.patterns import (banded_mask, random_mask,
+from repro.core.engine import _pack_wave, number_wave
+from repro.core.patterns import (banded_mask, divide_space_order,
+                                 overlap_mask, particle_cloud, random_mask,
                                  random_symmetric_mask, values_for_mask)
 
 N, LEAF_N, BS = 64, 16, 4
@@ -166,8 +172,7 @@ class TestLifecycle:
         st2 = sess.engine_stats()
         assert sum(st2["pushed_bytes"]) > sum(st1["pushed_bytes"])
 
-    @pytest.mark.parametrize("kernel", ["gemm", "pairs"])
-    def test_plan_replay_compiles_nothing(self, kernel):
+    def test_plan_replay_compiles_nothing(self):
         """Waves of one shape reuse one jitted executable: a rebound plan
         replay triggers no XLA compile."""
         import jax
@@ -176,7 +181,7 @@ class TestLifecycle:
 
         rng = np.random.default_rng(2)
         a = rng.standard_normal((N, N)) * 0.1
-        sess = Session(engine=MeshEngine(kernel=kernel), leaf_n=LEAF_N,
+        sess = Session(engine=MeshEngine(), leaf_n=LEAF_N,
                        bs=BS, lazy=True)
         X = sess.from_dense(a, name="X")
         plan = sess.compile(X @ X)
@@ -209,6 +214,94 @@ class TestLifecycle:
             assert len(st[key]) == n
             assert all(v >= 0 for v in st[key])
         assert st["waves"] == len(st["comm_log"]) > 0
+
+
+class TestWavePlan:
+    """``plan_mesh_wave`` against ``_pack_wave``, without devices."""
+
+    @staticmethod
+    def _wave(n_dev, product):
+        """The ready tasks of one overlap-pattern product, not run."""
+        from repro.launch.mesh_exec import MeshEngine
+
+        coords = particle_cloud(4, 3, seed=7)          # 64 basis functions
+        mask = overlap_mask(coords, 4.0, order=divide_space_order(coords))
+        x_val = values_for_mask(mask, seed=7, symmetric=True)
+        sess = Session(engine=MeshEngine(n_dev=n_dev), leaf_n=LEAF_N,
+                       bs=BS)
+        x = sess.from_dense(x_val, upper=product == "sym_square")
+        _ = x.sym_square() if product == "sym_square" else x @ x
+        (tasks,) = sess.graph.engine.ready_wave().values()
+        return tasks
+
+    @pytest.mark.parametrize("product", ["matmul", "sym_square"])
+    @pytest.mark.parametrize("n_dev", [1, 2, 3, 4])
+    def test_plan_splits_the_one_chip_rows(self, n_dev, product):
+        from repro.launch.mesh_exec import plan_mesh_wave
+
+        tasks = self._wave(n_dev, product)
+        slots, sa, sb, seg, n_slots = number_wave(tasks)
+        table, psa, psb, pseg, _, _ = _pack_wave(tasks)
+        plan = plan_mesh_wave(slots, sa, sb, seg,
+                              [len(t.out.blocks) for t in tasks], n_dev,
+                              {}, [dict() for _ in range(n_dev)])
+        # the contiguous balanced split of the tasks
+        assert (np.diff(plan.task_dev) >= 0).all()
+        per_dev = np.bincount(plan.task_dev, minlength=n_dev)
+        assert per_dev.max() - per_dev.min() <= 1
+        assert plan.n_out.sum() == n_slots
+        lo = 0
+        for d in range(n_dev):
+            pool = plan.pool_slot[d]
+            n = int((plan.seg[d] < plan.cap_c).sum())
+            rows = slice(lo, lo + n)
+            lo += n
+            # in order, the one-chip rows of this device's tasks: of the
+            # whole wave in its numbering, and of these tasks alone
+            np.testing.assert_array_equal(pool[plan.sa[d, :n]], psa[rows])
+            np.testing.assert_array_equal(pool[plan.sb[d, :n]], psb[rows])
+            np.testing.assert_array_equal(plan.seg[d, :n] + plan.base[d],
+                                          pseg[rows])
+            mine = [t for t, dev in zip(tasks, plan.task_dev) if dev == d]
+            if mine:
+                t_d, sa_d, sb_d, seg_d, _, _ = _pack_wave(mine)
+                np.testing.assert_array_equal(plan.seg[d, :n], seg_d)
+                assert table[pool[plan.sa[d, :n]]].tobytes() == \
+                    t_d[sa_d].tobytes()
+                assert table[pool[plan.sb[d, :n]]].tobytes() == \
+                    t_d[sb_d].tobytes()
+            # every slot it reads is its own or received, exactly once
+            read = np.unique(np.concatenate([psa[rows], psb[rows]]))
+            held = pool[pool >= 0]
+            assert len(np.unique(held)) == len(held)
+            assert np.isin(read, held).all()
+            own = pool[:plan.cap_own]
+            assert (plan.home[own[own >= 0]] == d).all()
+            assert (plan.home[pool[plan.cap_own:][
+                pool[plan.cap_own:] >= 0]] != d).all()
+            # with nothing resident yet, every remote read is one fetch
+            assert plan.fetched[d] == (plan.home[read] != d).sum()
+        assert lo == len(pseg)
+        assert plan.pushed.sum() == len(slots)
+        # the ring shifts deliver what the pools say they hold
+        off = plan.cap_own
+        for k, sel in zip(plan.shifts, plan.sels):
+            for src in range(n_dev):
+                got = plan.pool_slot[src, sel[src]]
+                want = plan.pool_slot[(src + k) % n_dev,
+                                      off:off + sel.shape[1]]
+                np.testing.assert_array_equal(got[want >= 0],
+                                              want[want >= 0])
+            off += sel.shape[1]
+        assert off == plan.pool_slot.shape[1]
+        # a leaf lives on the first device that reads any of its blocks
+        pair_dev = np.repeat(np.arange(n_dev), [
+            (plan.seg[d] < plan.cap_c).sum() for d in range(n_dev)])
+        leaf_of = np.array([id(leaf) for leaf, _, _ in slots])
+        for lid in set(leaf_of.tolist()):
+            mine = np.flatnonzero(leaf_of == lid)
+            readers = pair_dev[np.isin(psa, mine) | np.isin(psb, mine)]
+            assert (plan.home[mine] == readers.min()).all()
 
 
 @pytest.mark.slow

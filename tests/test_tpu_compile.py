@@ -6,8 +6,7 @@ TPU compiler, which refuses what the chip would refuse (SMEM or VMEM
 overflow, unaligned tiles, programs larger than device memory).  Sizes
 follow ``chip_smoke.py``: bs = 128 and the pair, operand and output-slot
 counts of its ``multiply`` phase (n = 32,768), the ``scf`` phase's leaf
-size, and the ``mesh`` phase's per-device wave on a four-device mesh,
-where the ``"gemm"`` kernel is shown not to fit.
+size, and the ``mesh`` phase's per-device wave on a four-device mesh.
 
 The topology is described only inside the fixture, never at import:
 one process may load the TPU library at a time.
@@ -97,7 +96,7 @@ _MESH_CAPS = dict(cap_own=10_747, cap_p=107_992, cap_c=52_575,
                   cnts=(4_000, 1_000, 4_000))
 
 
-def _lower_mesh_wave(topo, kernel, cap_own, cap_p, cap_c, cnts):
+def _lower_mesh_wave(topo, cap_own, cap_p, cap_c, cnts):
     n_dev = 4
     mesh = Mesh(np.asarray(topo.devices[:n_dev]), ("dev",))
     on_mesh = NamedSharding(mesh, P("dev"))
@@ -108,29 +107,14 @@ def _lower_mesh_wave(topo, kernel, cap_own, cap_p, cap_c, cnts):
     idx = s((cap_p,), jnp.int32)
     sels = tuple(s((c,), jnp.int32) for c in cnts)
     return mesh_wave.lower(
-        pool, idx, idx, idx, sels, mesh=mesh, kernel=kernel,
-        shifts=(1, 2, 3)[:len(cnts)], cap_c=cap_c, block_t=8,
+        pool, idx, idx, idx, sels, mesh=mesh,
+        shifts=(1, 2, 3)[:len(cnts)], cap_c=cap_c,
         use_pallas=True, interpret=False)
 
 
-@pytest.mark.parametrize("kernel", ["pairs", "gemm"])
-def test_mesh_wave_compiles(topo, kernel):
-    # "pairs", MeshEngine's default, at the mesh phase's size; "gemm" at
-    # a tenth of it (see the next test)
-    scale = 1 if kernel == "pairs" else 10
-    caps = {k: v // scale if k != "cnts" else tuple(c // scale for c in v)
-            for k, v in _MESH_CAPS.items()}
-    compiled = _lower_mesh_wave(topo, kernel, **caps).compile()
+def test_mesh_wave_compiles(topo):
+    compiled = _lower_mesh_wave(topo, **_MESH_CAPS).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
     assert _device_bytes(compiled) < HBM_BYTES
-
-
-def test_mesh_wave_gemm_exceeds_hbm_at_mesh_size(topo):
-    """The "gemm" kernel gathers both operands of every pair and their
-    products into (pairs, bs, bs) arrays, 3 x 64 KiB per pair at bs = 128:
-    at the mesh phase's size the compiler refuses it for lack of HBM.
-    This is why MeshEngine defaults to "pairs", which gathers by DMA."""
-    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
-        _lower_mesh_wave(topo, "gemm", **_MESH_CAPS).compile()
